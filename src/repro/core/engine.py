@@ -23,8 +23,6 @@ import time as _time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
 
-import contextlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -97,20 +95,6 @@ PAYLOAD_RETENTION_TRANSFERS = 64
 # a small window is ample; the bound keeps a long decode stream from pinning
 # every step's outputs server-side.
 DEDUP_WINDOW = 64
-
-
-@contextlib.contextmanager
-def _quiet_donation():
-    """Scope-suppress JAX's per-execution 'donated buffers were not usable'
-    UserWarning around a stateful step: on backends without donation (CPU)
-    the executable falls back to copying, which is semantically fine here —
-    the warning would fire every decode step.  Scoped, not module-level, so
-    applications keep the signal for their own jits."""
-    with warnings.catch_warnings():
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable"
-        )
-        yield
 
 
 def _avals_nbytes(avals) -> int:
@@ -705,10 +689,9 @@ class BoundSegmentedReplay:
             ]
             if spec["stateful"]:
                 boundary = [val[t] for t in spec["boundary_tids"]]
-                with _quiet_donation():
-                    outs, new_carried = spec["fn"](
-                        params, boundary, self.carried_state
-                    )
+                outs, new_carried = spec["fn"](
+                    params, boundary, self.carried_state
+                )
                 self.carried_state = list(new_carried)
                 val.update(zip(spec["out_tids"], outs))
                 # publish the carried outputs too: a wire D2H that reads the
@@ -922,8 +905,13 @@ class OffloadServer:
         name: str = "server",
         tracer: Optional[Tracer] = None,
         verify: bool = False,
+        jax_device: Optional[Any] = None,
     ):
         self.device = device
+        # where this server's memory lives: H2D payloads land here as device
+        # arrays and stay resident between replayed steps (None: JAX's
+        # default device)
+        self.jax_device = jax_device
         self.name = name
         self.tracer = tracer
         self.execute = execute  # False: account time/bytes only (no compute)
@@ -940,6 +928,21 @@ class OffloadServer:
         # from advancing the donated KV cache twice.
         self.dedup: Dict[str, Dict[int, Any]] = {}
         self.dedup_hits = 0
+
+    def to_device(self, value: Any) -> Any:
+        """Place one buffer in this server's device memory."""
+        return jax.device_put(value, self.jax_device)
+
+    def receive_env(self, client_id: str, env: Dict[int, Any]) -> float:
+        """Install another server's device-memory namespace for
+        ``client_id`` (a migration or a checkpoint restore), placing every
+        buffer on this server's device; returns the bytes moved."""
+        dst = self.context(client_id).env
+        moved = 0.0
+        for addr, val in env.items():
+            dst[addr] = self.to_device(val)
+            moved += float(dst[addr].nbytes)
+        return moved
 
     def context(self, client_id: str = DEFAULT_CLIENT) -> ClientContext:
         ctx = self.contexts.get(client_id)
@@ -964,7 +967,9 @@ class OffloadServer:
         ret: Any = "cudaSuccess"
         if rec.func == FUNC_H2D:
             if self.execute:
-                env[call.out_addrs[0]] = np.asarray(call.h2d_value)
+                env[call.out_addrs[0]] = self.to_device(
+                    np.asarray(call.h2d_value)
+                )
         elif rec.func == FUNC_D2H:
             addr = call.in_operands[0][1]
             # DtoH must drain the kernel queue first
@@ -1182,12 +1187,11 @@ class OffloadServer:
                 )
             if fresh_carried:
                 for idx, v in fresh_carried.items():
-                    bound.carried_state[idx] = jnp.asarray(v)
+                    bound.carried_state[idx] = self.to_device(v)
             wire = [np.asarray(x) for x in inputs]
-            with _quiet_donation():
-                wire_outs, new_carried = program.step_fn(
-                    params_flat, wire, bound.carried_state
-                )
+            wire_outs, new_carried = program.step_fn(
+                params_flat, wire, bound.carried_state
+            )
             bound.carried_state = list(new_carried)
             wire_outs = [np.asarray(o) for o in wire_outs]
             self._refresh_env(ctx, bound, wire, wire_outs)
@@ -1291,7 +1295,7 @@ class OffloadServer:
                 f"carried-state arity mismatch: {len(state)} tensors for "
                 f"{len(pairs)} carried pairs"
             )
-        bound.carried_state = [jnp.asarray(v) for v in state]
+        bound.carried_state = [self.to_device(v) for v in state]
         if isinstance(bound, BoundSegmentedReplay):
             # segmented binding: the carried buffers live at the graph's
             # carried-output tensor addresses (what seed_carried reads back)

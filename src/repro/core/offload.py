@@ -277,9 +277,11 @@ class OffloadSession:
             jaxprs = [self._steady_jaxpr]
             if self._setup_jaxpr is not None:
                 jaxprs.insert(0, self._setup_jaxpr)
-            for cj in jaxprs:
-                for c in cj.consts:
-                    k = self._const_key(c)
+            const_keys = [
+                [self._const_key(c) for c in cj.consts] for cj in jaxprs
+            ]
+            for cj, cks in zip(jaxprs, const_keys):
+                for c, k in zip(cj.consts, cks):
                     if k not in registry:
                         registry[k] = -1
                         unique.append(np.asarray(c))
@@ -287,7 +289,13 @@ class OffloadSession:
             addrs = self.interceptor.upload_params(unique)
             for k, a in zip(keys, addrs):
                 registry[k] = a
-            self._const_registry = registry
+            # each jaxpr's constvar addresses, resolved once here: hashing
+            # every weight again on each intercepted inference would cost a
+            # full pass over the model per token
+            self._param_addrs = {
+                id(cj): [registry[k] for k in cks]
+                for cj, cks in zip(jaxprs, const_keys)
+            }
         self.stage_marks["after_load"] = (
             len(self.client.logs) if self.client else 0
         )
@@ -295,7 +303,7 @@ class OffloadSession:
 
     # ------------------------------------------------------------------
     def _param_addrs_for(self, closed_jaxpr) -> List[int]:
-        return [self._const_registry[self._const_key(c)] for c in closed_jaxpr.consts]
+        return self._param_addrs[id(closed_jaxpr)]
 
     def _steady_invars(self, inputs: Sequence[Any]):
         """One steady inference's invar values (in order) + resident map

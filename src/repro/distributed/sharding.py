@@ -16,64 +16,13 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def compat_make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types across jax versions.
-
-    jax >= 0.5 takes ``axis_types`` (and tests there want explicit
-    ``AxisType.Auto`` to silence the implicit-sharding migration); jax < 0.5
-    predates the enum and its ``make_mesh`` accepts no such keyword.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            axis_shapes,
-            axis_names,
-            devices=devices,
-            axis_types=(axis_type.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def use_mesh(mesh):
-    """``jax.set_mesh(mesh)`` across jax versions (context-manager form).
-
-    On jax < 0.5 the equivalent context is the physical mesh itself
-    (``with mesh:``), which installs the thread-local mesh that
-    :func:`current_abstract_mesh` falls back to.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def get_shard_map():
-    """``jax.shard_map`` on jax >= 0.5, the experimental export before it."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
-def current_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh()`` across jax versions.
-
-    jax >= 0.5 exposes the thread-local abstract mesh directly; on older
-    releases the only reliable context signal is the physical mesh installed
-    by ``with mesh:``, which carries an equivalent ``.abstract_mesh`` view.
-    Returns None when no mesh context is active.
-    """
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        return gam()
-    from jax._src import mesh as _mesh_impl  # jax < 0.5 fallback
-
-    env_mesh = _mesh_impl.thread_resources.env.physical_mesh
-    if env_mesh.empty:
-        return None
-    return env_mesh.abstract_mesh
+def auto_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis of the ``Auto`` type."""
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 def _phys_axes(axis, mesh_axis_names) -> Any:
@@ -108,8 +57,8 @@ def translate_tree(tree, mesh_axis_names: Sequence[str]):
 
 def maybe_shard(x, spec: P):
     """Apply a logical sharding constraint iff a mesh context is active."""
-    mesh = current_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     return jax.lax.with_sharding_constraint(
         x, translate_spec(spec, mesh.axis_names)

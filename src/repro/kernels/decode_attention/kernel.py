@@ -11,6 +11,13 @@ Grid: (batch, kv_heads, kv_blocks); the kv-block axis is innermost/sequential
 so m/l/acc scratch carries across cache tiles — the classic split-KV reduce
 expressed TPU-natively (sequential grid instead of a second combine kernel).
 
+The cache is viewed as (B, S, Hkv*D) — a free reshape of the (B, S, Hkv, D)
+layout — so a KV head's tile is a (block_k, D) column slab: the head axis
+never sits in the second-minor block position, where the chip's compiler
+only accepts blocks of 8 rows or the whole axis.  On the chip this needs
+D % 128 == 0 (the lane width); ``ops.decode_attention_path`` names the
+shapes that take the reference instead.
+
 ``kv_len`` rides in SMEM (scalar per batch row) and masks the tail tile.
 """
 from __future__ import annotations
@@ -29,10 +36,10 @@ _MIN_ROWS = 8  # VPU sublane count — pad q-head group rows up to this
 
 
 def _decode_kernel(
-    kv_len_ref,   # SMEM (1,)
+    kv_len_ref,   # SMEM (B,), scalar-prefetched
     q_ref,        # (1, 1, rows, d)
-    k_ref,        # (1, block_k, 1, d)
-    v_ref,        # (1, block_k, 1, d)
+    k_ref,        # (1, block_k, d)
+    v_ref,        # (1, block_k, d)
     o_ref,        # (1, 1, rows, d)
     m_scratch,
     l_scratch,
@@ -62,8 +69,8 @@ def _decode_kernel(
     @pl.when(run)
     def _body():
         q = q_ref[0, 0, :, :].astype(jnp.float32)          # (rows, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (block_k, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)                    # (block_k, d)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale                                        # (rows, block_k)
@@ -116,6 +123,9 @@ def decode_attention_pallas(
     if pad:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
+    # (B, S, Hkv*D): KV head g is the column block g of width D
+    k2 = k_cache.reshape(b, s, hkv * d)
+    v2 = v_cache.reshape(b, s, hkv * d)
     kernel = functools.partial(
         _decode_kernel,
         block_k=block_k,
@@ -123,22 +133,32 @@ def decode_attention_pallas(
         window=window,
         sm_scale=1.0 / float(d) ** 0.5,
     )
+    # kv_len is scalar-prefetched into SMEM: a prefetch operand keeps the
+    # kernel legal under jax.vmap (the batched server step), where a plain
+    # SMEM block of the now two-dimensional lengths is refused
     out = pl.pallas_call(
         kernel,
-        grid=(b, hkv, s // block_k),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, rows, d), lambda b_, g, ki: (b_, g, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, g, ki: (b_, ki, g, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, g, ki: (b_, ki, g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, d), lambda b_, g, ki: (b_, g, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, s // block_k),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, 1, rows, d), lambda b_, g, ki, lens: (b_, g, 0, 0)
+                ),
+                pl.BlockSpec((1, block_k, d), lambda b_, g, ki, lens: (b_, ki, g)),
+                pl.BlockSpec((1, block_k, d), lambda b_, g, ki, lens: (b_, ki, g)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, rows, d), lambda b_, g, ki, lens: (b_, g, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, d), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
-        ],
         interpret=interpret,
-    )(kv_len.astype(jnp.int32), qg, k_cache, v_cache)
+        name="decode_attention",
+    )(kv_len.astype(jnp.int32), qg, k2, v2)
     return out[:, :, :n_rep, :].reshape(b, hq, d)

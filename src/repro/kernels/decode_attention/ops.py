@@ -2,22 +2,44 @@
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attention.kernel import decode_attention_pallas
+from repro.kernels.decode_attention.kernel import (
+    DEFAULT_BLOCK_K,
+    decode_attention_pallas,
+)
 from repro.kernels.decode_attention.ref import decode_attention_ref
+from repro.kernels.dispatch import REFERENCE, KernelPath, choose
 
 
-def _pallas_supported(q, k_cache) -> bool:
-    b, hq, d = q.shape
-    _, s, hkv, _ = k_cache.shape
-    return (
-        jax.default_backend() == "tpu"
-        and d in (64, 128, 256)
-        and s % 512 == 0
+def decode_attention_path(
+    q_shape: Sequence[int],
+    k_shape: Sequence[int],
+    *,
+    interpret: bool = False,
+    force_ref: bool = False,
+) -> KernelPath:
+    """The implementation a call with these shapes takes.  On the chip the
+    kernel's (block_k, D) KV tiles need D to fill whole 128-wide lanes, and
+    a cache longer than one tile must split into whole tiles."""
+    d = q_shape[-1]
+    s = k_shape[1]
+    refusal = None
+    if d % 128:
+        refusal = f"head dim {d} is not a multiple of 128"
+    elif s > DEFAULT_BLOCK_K and s % DEFAULT_BLOCK_K:
+        refusal = (
+            f"cache length {s} is not a multiple of the {DEFAULT_BLOCK_K}-row "
+            "tile"
+        )
+    elif s % 8:
+        refusal = f"cache length {s} is not a multiple of 8"
+    return choose(
+        "decode_attention", interpret=interpret, force_ref=force_ref,
+        refusal=refusal,
     )
 
 
@@ -33,13 +55,15 @@ def decode_attention(
     force_ref: bool = False,
 ) -> jnp.ndarray:
     """q (B,Hq,D) × cache (B,S,Hkv,D), valid lengths (B,) -> (B,Hq,D)."""
-    if force_ref:
+    path = decode_attention_path(
+        q.shape, k_cache.shape, interpret=interpret, force_ref=force_ref
+    )
+    if path.impl == REFERENCE:
         return decode_attention_ref(q, k_cache, v_cache, kv_len, window=window)
-    if interpret or _pallas_supported(q, k_cache):
-        return decode_attention_pallas(
-            q, k_cache, v_cache, kv_len, window=window, interpret=interpret
-        )
-    return decode_attention_ref(q, k_cache, v_cache, kv_len, window=window)
+    return decode_attention_pallas(
+        q, k_cache, v_cache, kv_len, window=window,
+        interpret=interpret,
+    )
 
 
-__all__ = ["decode_attention", "decode_attention_ref"]
+__all__ = ["decode_attention", "decode_attention_path", "decode_attention_ref"]

@@ -13,6 +13,11 @@ Block sizes default to (128, 128): MXU-aligned (multiples of 8×128 for f32,
 
 GQA is expressed in the k/v BlockSpec index maps (kv head = q head // n_rep)
 so no KV replication ever materializes.
+
+The kernel runs head-major, (B, H, S, D): each block is a (block, D) tile of
+one head, so the head axis never sits in the second-minor block position,
+where the chip's compiler only accepts 8-row multiples or the whole axis.
+The wrapper transposes in and out of the (B, S, H, D) layout the model uses.
 """
 from __future__ import annotations
 
@@ -70,9 +75,9 @@ def _flash_fwd_kernel(
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)        # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)        # (bk, d)
+        q = q_ref[0, 0].astype(jnp.float32)              # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)              # (bk, d)
+        v = v_ref[0, 0].astype(jnp.float32)              # (bk, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bq, bk)
@@ -105,7 +110,7 @@ def _flash_fwd_kernel(
     @pl.when(ki == num_kv_blocks - 1)
     def _finalize():
         denom = jnp.maximum(l_scratch[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scratch[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scratch[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -144,30 +149,35 @@ def flash_attention_pallas(
         num_kv_blocks=sk // block_k,
         sm_scale=1.0 / float(d) ** 0.5,
     )
-    return pl.pallas_call(
+    qh = jnp.swapaxes(q, 1, 2)                         # (B, Hq, Sq, D)
+    kh = jnp.swapaxes(k, 1, 2)                         # (B, Hkv, Sk, D)
+    vh = jnp.swapaxes(v, 1, 2)
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (1, block_q, 1, d), lambda b_, h, qi, ki: (b_, qi, h, 0)
+                (1, 1, block_q, d), lambda b_, h, qi, ki: (b_, h, qi, 0)
             ),
             pl.BlockSpec(
-                (1, block_k, 1, d),
-                lambda b_, h, qi, ki, n_rep=n_rep: (b_, ki, h // n_rep, 0),
+                (1, 1, block_k, d),
+                lambda b_, h, qi, ki, n_rep=n_rep: (b_, h // n_rep, ki, 0),
             ),
             pl.BlockSpec(
-                (1, block_k, 1, d),
-                lambda b_, h, qi, ki, n_rep=n_rep: (b_, ki, h // n_rep, 0),
+                (1, 1, block_k, d),
+                lambda b_, h, qi, ki, n_rep=n_rep: (b_, h // n_rep, ki, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, block_q, 1, d), lambda b_, h, qi, ki: (b_, qi, h, 0)
+            (1, 1, block_q, d), lambda b_, h, qi, ki: (b_, h, qi, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # m
             pltpu.VMEM((block_q, 1), jnp.float32),   # l
             pltpu.VMEM((block_q, d), jnp.float32),   # acc
         ],
         interpret=interpret,
-    )(q, k, v)
+        name="flash_attention",
+    )(qh, kh, vh)
+    return jnp.swapaxes(out, 1, 2)

@@ -37,20 +37,30 @@ def rmsnorm_pallas(
     for s in x.shape[:-1]:
         rows *= s
     x2 = x.reshape(rows, d)
-    block_rows = min(block_rows, rows)
-    if rows % block_rows:
-        # fall back to a row count that divides
-        block_rows = 1
+    if rows <= block_rows:
+        # one block holding every row: a block equal to the whole array is
+        # legal on the chip whatever the row count
+        block_rows = rows
+        pad = 0
+    else:
+        # whole tiles of block_rows (a multiple of 8, as the chip's compiler
+        # requires of a second-minor block); the padded rows are sliced off
+        pad = (-rows) % block_rows
+        if pad:
+            x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     kernel = functools.partial(_rmsnorm_kernel, eps=eps, offset=offset)
     out = pl.pallas_call(
         kernel,
-        grid=(rows // block_rows,),
+        grid=((rows + pad) // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
             pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale.reshape(1, d))
+    if pad:
+        out = out[:rows]
     return out.reshape(orig_shape)

@@ -6,8 +6,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import REFERENCE, KernelPath, choose
 from repro.kernels.rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
+
+
+def rmsnorm_path(*, interpret: bool = False, force_ref: bool = False) -> KernelPath:
+    """The implementation an RMSNorm call takes: every shape compiles for
+    the chip (rows are padded to whole tiles)."""
+    return choose("rmsnorm", interpret=interpret, force_ref=force_ref)
 
 
 @partial(jax.jit, static_argnames=("eps", "offset", "interpret", "force_ref"))
@@ -20,11 +27,9 @@ def rmsnorm(
     interpret: bool = False,
     force_ref: bool = False,
 ) -> jnp.ndarray:
-    if force_ref:
+    if rmsnorm_path(interpret=interpret, force_ref=force_ref).impl == REFERENCE:
         return rmsnorm_ref(x, scale, eps, offset)
-    if interpret or jax.default_backend() == "tpu":
-        return rmsnorm_pallas(x, scale, eps, offset, interpret=interpret)
-    return rmsnorm_ref(x, scale, eps, offset)
+    return rmsnorm_pallas(x, scale, eps, offset, interpret=interpret)
 
 
-__all__ = ["rmsnorm", "rmsnorm_ref"]
+__all__ = ["rmsnorm", "rmsnorm_path", "rmsnorm_ref"]

@@ -3,6 +3,12 @@
 Serves both Mamba2 (ld = dt*A, gi = dt) and mLSTM (ld = logsigmoid(f), gi =
 exp(i), B/C/x = k/q/v) — see ref.py for the algebra.
 
+Operands are head-major, (B, H, S, ·), so each block is a (chunk, width)
+tile of one head: the chip's compiler accepts a second-minor block only of
+8-row multiples or the whole axis, so the head axis cannot sit there.  The
+per-step scalars (log-decay, input scale) travel both as a row and as a
+column, which keeps every value in the kernel two-dimensional.
+
 Grid: (batch, heads, chunks) with the chunk axis innermost/sequential — the
 inter-chunk state h (N x P) lives in VMEM scratch and is carried across chunk
 iterations, so the whole recurrence runs in one kernel launch with no HBM
@@ -27,12 +33,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(
     D_ref,      # SMEM (H,)
-    x_ref,      # (1, Q, 1, P)
-    ld_ref,     # (1, Q, 1)
-    gi_ref,     # (1, Q, 1)
-    B_ref,      # (1, Q, 1, N)
-    C_ref,      # (1, Q, 1, N)
-    y_ref,      # (1, Q, 1, P)
+    x_ref,      # (1, 1, Q, P)
+    ldr_ref,    # (1, 1, 1, Q)  log-decay as a row
+    ldc_ref,    # (1, 1, Q, 1)  ... and as a column
+    gir_ref,    # (1, 1, 1, Q)  input scale as a row
+    gic_ref,    # (1, 1, Q, 1)  ... and as a column
+    B_ref,      # (1, 1, Q, N)
+    C_ref,      # (1, 1, Q, N)
+    y_ref,      # (1, 1, Q, P)
     hout_ref,   # (1, 1, N, P)
     h_scratch,  # VMEM (N, P)
     *,
@@ -47,46 +55,50 @@ def _ssd_kernel(
     def _init():
         h_scratch[...] = jnp.zeros_like(h_scratch)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)          # (Q, P)
-    ld = ld_ref[0, :, 0].astype(jnp.float32)           # (Q,)
-    gi = gi_ref[0, :, 0].astype(jnp.float32)           # (Q,)
-    Bm = B_ref[0, :, 0, :].astype(jnp.float32)         # (Q, N)
-    Cm = C_ref[0, :, 0, :].astype(jnp.float32)         # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)                # (Q, P)
+    ld_r = ldr_ref[0, 0].astype(jnp.float32)           # (1, Q)
+    ld_c = ldc_ref[0, 0].astype(jnp.float32)           # (Q, 1)
+    gi_r = gir_ref[0, 0].astype(jnp.float32)           # (1, Q)
+    gi_c = gic_ref[0, 0].astype(jnp.float32)           # (Q, 1)
+    Bm = B_ref[0, 0].astype(jnp.float32)               # (Q, N)
+    Cm = C_ref[0, 0].astype(jnp.float32)               # (Q, N)
 
-    cs = jnp.cumsum(ld)                                # inclusive
-    diff = cs[:, None] - cs[None, :]
-    causal = (
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    )
-    decay = jnp.where(causal, jnp.exp(diff), 0.0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = row >= col
+    # inclusive prefix sums of the log-decay as a column and as a row, by
+    # masked reductions (2-D only: no 1-D vectors or cumsum in the kernel)
+    cs_c = jnp.sum(jnp.where(causal, ld_r, 0.0), axis=1, keepdims=True)
+    cs_r = jnp.sum(jnp.where(row <= col, ld_c, 0.0), axis=0, keepdims=True)
+    total = jnp.sum(ld_c, axis=0, keepdims=True)       # (1, 1)
+    decay = jnp.where(causal, jnp.exp(cs_c - cs_r), 0.0)
 
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    scores = scores * decay * gi[None, :]
+    scores = scores * decay * gi_r
     y = jax.lax.dot_general(
         scores, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     h_prev = h_scratch[...]                             # (N, P)
-    y = y + jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cs_c) * jax.lax.dot_general(
         Cm, h_prev, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     if use_d:
         y = y + x * D_ref[hi]
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    decay_to_end = jnp.exp(cs[-1] - cs) * gi            # (Q,)
-    h_new = jnp.exp(cs[-1]) * h_prev + jax.lax.dot_general(
-        Bm * decay_to_end[:, None], x, (((0,), (0,)), ((), ())),
+    decay_to_end = jnp.exp(total - cs_c) * gi_c         # (Q, 1)
+    h_new = jnp.exp(total) * h_prev + jax.lax.dot_general(
+        Bm * decay_to_end, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     h_scratch[...] = h_new
 
     @pl.when(ci == num_chunks - 1)
     def _emit_state():
-        hout_ref[0, 0, :, :] = h_new.astype(hout_ref.dtype)
+        hout_ref[0, 0] = h_new.astype(hout_ref.dtype)
 
 
 def gated_scan_pallas(
@@ -111,6 +123,17 @@ def gated_scan_pallas(
     use_d = D is not None
     d_arr = (D if use_d else jnp.zeros((h,), jnp.float32)).astype(jnp.float32)
 
+    # head-major operands: every block is a (chunk, width) tile of one head
+    xh = jnp.swapaxes(x, 1, 2)                          # (B, H, S, P)
+    ld = jnp.swapaxes(log_decay, 1, 2)                  # (B, H, S)
+    gi = jnp.swapaxes(in_scale, 1, 2)
+    Bh = jnp.swapaxes(Bm, 1, 2)                         # (B, G, S, N)
+    Ch = jnp.swapaxes(Cm, 1, 2)
+    row_spec = pl.BlockSpec((1, 1, 1, chunk), lambda b_, h_, c: (b_, h_, 0, c))
+    col_spec = pl.BlockSpec((1, 1, chunk, 1), lambda b_, h_, c: (b_, h_, c, 0))
+    group_spec = pl.BlockSpec(
+        (1, 1, chunk, n), lambda b_, h_, c, rep=rep: (b_, h_ // rep, c, 0)
+    )
     kernel = functools.partial(
         _ssd_kernel, chunk=chunk, num_chunks=nc, use_d=use_d
     )
@@ -119,28 +142,30 @@ def gated_scan_pallas(
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, c: (b_, c, h_, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b_, h_, c: (b_, c, h_)),
-            pl.BlockSpec((1, chunk, 1), lambda b_, h_, c: (b_, c, h_)),
-            pl.BlockSpec(
-                (1, chunk, 1, n), lambda b_, h_, c, rep=rep: (b_, c, h_ // rep, 0)
-            ),
-            pl.BlockSpec(
-                (1, chunk, 1, n), lambda b_, h_, c, rep=rep: (b_, c, h_ // rep, 0)
-            ),
+            pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c: (b_, h_, c, 0)),
+            row_spec,
+            col_spec,
+            row_spec,
+            col_spec,
+            group_spec,
+            group_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, c: (b_, c, h_, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c: (b_, h_, c, 0)),
             pl.BlockSpec((1, 1, n, p), lambda b_, h_, c: (b_, h_, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct(xh.shape, x.dtype),
             jax.ShapeDtypeStruct((b, h, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(d_arr, x, log_decay, in_scale, Bm, Cm)
-    return y, h_final
+        name="gated_scan",
+    )(
+        d_arr, xh, ld[:, :, None, :], ld[..., None], gi[:, :, None, :],
+        gi[..., None], Bh, Ch,
+    )
+    return jnp.swapaxes(y, 1, 2), h_final
 
 
 def ssm_scan_pallas(

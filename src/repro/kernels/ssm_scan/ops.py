@@ -7,6 +7,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import REFERENCE, KernelPath, choose
 from repro.kernels.ssm_scan.kernel import gated_scan_pallas
 from repro.kernels.ssm_scan.ref import (
     gated_scan_ref,
@@ -20,6 +21,24 @@ def _pad_seq(arr, pad, value=0.0):
     cfgpad = [(0, 0)] * arr.ndim
     cfgpad[1] = (0, pad)
     return jnp.pad(arr, cfgpad, constant_values=value)
+
+
+def gated_scan_path(
+    chunk: int, seq: int, *, interpret: bool = False, force_ref: bool = False
+) -> KernelPath:
+    """The implementation a scan of ``seq`` (chunk-padded) steps in chunks
+    of ``chunk`` takes: on the chip a chunk is a lane row of per-step
+    scalars, so it must be a multiple of 128 unless it is the whole
+    sequence."""
+    refusal = (
+        f"chunk {chunk} of a {seq}-step scan is not a multiple of 128"
+        if chunk % 128 and chunk != seq
+        else None
+    )
+    return choose(
+        "gated_scan", interpret=interpret, force_ref=force_ref,
+        refusal=refusal,
+    )
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret", "force_ref"))
@@ -38,14 +57,15 @@ def gated_scan(
     else:
         x_, ld_, gi_, Bm_, Cm_ = x, log_decay, in_scale, Bm, Cm
 
-    if force_ref:
+    path = gated_scan_path(
+        eff_chunk, s + pad, interpret=interpret, force_ref=force_ref
+    )
+    if path.impl == REFERENCE:
         y, h = gated_scan_ref(x_, ld_, gi_, Bm_, Cm_, D, chunk=eff_chunk)
-    elif interpret or jax.default_backend() == "tpu":
+    else:
         y, h = gated_scan_pallas(
             x_, ld_, gi_, Bm_, Cm_, D, chunk=eff_chunk, interpret=interpret
         )
-    else:
-        y, h = gated_scan_ref(x_, ld_, gi_, Bm_, Cm_, D, chunk=eff_chunk)
     return (y[:, :s] if pad else y), h
 
 
@@ -65,6 +85,6 @@ ssm_step = jax.jit(ssm_step_ref)
 gated_step = jax.jit(gated_step_ref)
 
 __all__ = [
-    "gated_scan", "gated_step", "ssm_scan", "ssm_step",
+    "gated_scan", "gated_scan_path", "gated_step", "ssm_scan", "ssm_step",
     "gated_scan_ref", "gated_step_ref", "ssm_scan_ref", "ssm_step_ref",
 ]

@@ -30,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import CONFIGS, SHAPES
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.distributed.sharding import translate_tree, use_mesh
+from repro.distributed.sharding import translate_tree
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.mesh import make_production_mesh, mesh_dp_size
 from repro.models.registry import (
@@ -149,7 +149,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh) -> Dict[str, Any]:
     p_shard = _sharding_tree(p_specs, mesh, p_struct)
     rep = NamedSharding(mesh, P())
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             train_step = make_train_step(cfg, remat=True)
             opt_struct = jax.eval_shape(init_opt_state, p_struct)

@@ -309,3 +309,28 @@ def analyze_hlo(hlo: str) -> Dict[str, Any]:
         "n_computations": len(comps),
         "entry": entry,
     }
+
+
+_TPU_CALL = re.compile(
+    r'^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=.*custom_call_target="tpu_custom_call"'
+)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def tpu_custom_calls(hlo: str) -> List[str]:
+    """One entry per Pallas kernel call compiled into a TPU executable: the
+    instruction name and its ``op_name`` (which carries the
+    ``pallas_call(name=...)``, also under ``jax.vmap`` where XLA renames the
+    instruction itself)."""
+    calls = []
+    for line in hlo.splitlines():
+        m = _TPU_CALL.match(line)
+        if m:
+            op = _OP_NAME.search(line)
+            calls.append(f"{m.group(1)} {op.group(1) if op else ''}")
+    return calls
+
+
+def has_tpu_kernel(hlo: str, name: str) -> bool:
+    """Whether the kernel ``name`` is compiled into the executable."""
+    return any(name in call for call in tpu_custom_calls(hlo))

@@ -112,10 +112,6 @@ def _sp_decode_attention(q, k_cache, v_cache, kv_len, cfg, mesh):
     chips.  This is what GSPMD fails to find for the masked-softmax pattern
     (it replicates the cache instead — 'involuntary full rematerialization').
     """
-    from repro.distributed.sharding import get_shard_map
-
-    shard_map = get_shard_map()
-
     b, hq, d = q.shape
     _, s, hkv, _ = k_cache.shape
     n_rep = hq // hkv
@@ -161,7 +157,7 @@ def _sp_decode_attention(q, k_cache, v_cache, kv_len, cfg, mesh):
     q4 = q.reshape(b, 1, hq, d)
     kv_spec = P(dp_axes if b >= 16 else None, tp, None, None)
     qspec = P(dp_axes if b >= 16 else None, None, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(qspec, kv_spec, kv_spec, P(dp_axes if b >= 16 else None)),
@@ -199,12 +195,9 @@ def attn_decode_step(
         return out, new_cache
     k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, pos, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, pos, 0, 0))
-    from repro.distributed.sharding import current_abstract_mesh
-
-    mesh = current_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if (
         getattr(cfg, "sp_decode", False)
-        and mesh is not None
         and not mesh.empty
         and "model" in mesh.axis_names
         and k_cache.shape[1] % mesh.shape["model"] == 0

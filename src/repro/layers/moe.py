@@ -111,9 +111,6 @@ def _local_dispatch_shardmap(p, x, cfg, mesh):
     down-projection.  GSPMD's scatter partitioner replicates the global-token
     dispatch (measured in EXPERIMENTS.md §Perf) — shard_map removes its
     freedom to do so."""
-    from repro.distributed.sharding import get_shard_map
-
-    shard_map = get_shard_map()
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -184,7 +181,7 @@ def _local_dispatch_shardmap(p, x, cfg, mesh):
         return y.reshape(1, t_local, d)
 
     xg = x.reshape(dp_size, t_local, d)
-    yg = shard_map(
+    yg = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(dp_axes, None, None), w_specs["router"], w_specs["w_gate"],
@@ -198,16 +195,11 @@ def moe_apply(p: Dict[str, Any], x: jnp.ndarray, cfg) -> jnp.ndarray:
     """cfg.moe_groups == 0 (baseline): one global dispatch over all tokens
     under GSPMD.  cfg.moe_groups > 0 (optimized): explicit shard_map dispatch
     with shard-local routing (EXPERIMENTS.md §Perf)."""
-    import jax as _jax
-
     b, s, d = x.shape
     t = b * s
-    from repro.distributed.sharding import current_abstract_mesh
-
-    mesh = current_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     use_sm = (
         cfg.moe_groups
-        and mesh is not None
         and not mesh.empty
         and "model" in mesh.axis_names
     )

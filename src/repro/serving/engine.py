@@ -301,6 +301,7 @@ class MultiClientServedLM:
         batch_window_s: float = 2e-3,
         edge: Optional[RRTOEdgeServer] = None,
         stateful: bool = True,
+        params=None,
     ):
         if num_clients < 1:
             raise ValueError(f"need at least one client, got {num_clients}")
@@ -312,7 +313,8 @@ class MultiClientServedLM:
         # executable (not just the IOS) is shareable verbatim — and in the
         # stateful formulation, same-round decode submissions run as one true
         # vmap-batched stateful step over the stacked per-client KV caches
-        params = model.init_params(jax.random.PRNGKey(seed), cfg)
+        if params is None:
+            params = model.init_params(jax.random.PRNGKey(seed), cfg)
         self.edge = edge or RRTOEdgeServer(
             execute=execute,
             cache_capacity=cache_capacity,

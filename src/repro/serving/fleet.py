@@ -55,7 +55,7 @@ import os
 import tempfile
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
+import jax
 
 from repro.core.costmodel import GTX_2080TI, DeviceSpec
 from repro.core.engine import SimClock
@@ -418,6 +418,9 @@ class EdgeFleet:
     ):
         if n_replicas < 1:
             raise ValueError(f"need at least one replica, got {n_replicas}")
+        # replica i serves from device i % len(devices): on a four-chip host
+        # each of four replicas owns a chip
+        devices = jax.devices()
         self.clock = SimClock()
         self.timeline = EventTimeline()
         self.tracer = tracer
@@ -441,6 +444,7 @@ class EdgeFleet:
                     ingress=ingresses[i],
                     clock=self.clock,
                     name=f"r{i}",
+                    jax_device=devices[i % len(devices)],
                     tracer=tracer,
                     metrics=self.metrics.scope(f"r{i}"),
                     fault=fault,
@@ -682,11 +686,9 @@ class EdgeFleet:
         dst.edge.adopt_session(sess)
         moved = 0.0
         if src_ctx is not None:
-            dst_ctx = dst.edge.server.context(client_id)
-            dst_ctx.env.update(src_ctx.env)
-            moved = float(
-                sum(np.asarray(v).nbytes for v in src_ctx.env.values())
-            )
+            # device to device: the buffers land in the destination
+            # server's own memory
+            moved = dst.edge.server.receive_env(client_id, src_ctx.env)
             self.stats.migration_bytes += moved
             # replica-to-replica state transfer rides the site backhaul,
             # not any client radio
@@ -833,10 +835,7 @@ class EdgeFleet:
         self.replicate_caches()
         src.edge.disconnect(client_id)
         dst.edge.adopt_session(sess)
-        dst_ctx = dst.edge.server.context(client_id)
-        dst_ctx.env.update(
-            {addr: np.asarray(v) for addr, v in ckpt.env.items()}
-        )
+        dst.edge.server.receive_env(client_id, ckpt.env)
         self.backhaul.bytes_total += ckpt.nbytes
         if cl.ios is not None:
             dst.edge.server.prepare_replay(

@@ -506,8 +506,12 @@ class ReplayBatcher:
             for mine, other in zip(params, theirs):
                 if mine is other:
                     continue
-                a, b = np.asarray(mine), np.asarray(other)
-                if a.shape != b.shape or a.dtype != b.dtype or not np.array_equal(a, b):
+                # compared where the buffers live: no host round trip
+                if (
+                    mine.shape != other.shape
+                    or mine.dtype != other.dtype
+                    or not bool(jnp.array_equal(mine, other))
+                ):
                     return None
         return params
 
@@ -519,7 +523,7 @@ class ReplayBatcher:
     ) -> Optional[_BatchGroup]:
         """Execute the whole group as one ``jax.vmap``-compiled batched call;
         returns per-member outputs (and carried states) keyed by client id."""
-        from repro.core.engine import BatchedReplayProgram, _quiet_donation
+        from repro.core.engine import BatchedReplayProgram
 
         program = self.server.context(members[0][0].client_id).replay.program
         if not members[0][1] and not program.is_stateful:
@@ -579,10 +583,9 @@ class ReplayBatcher:
                 jnp.stack([st[k] for st in states] + [states[0][k]] * pad)
                 for k in range(len(states[0]))
             ]
-            with _quiet_donation():
-                wire_outs, new_carried = batched.fn(
-                    params_flat, stacked_inputs, stacked_state
-                )
+            wire_outs, new_carried = batched.fn(
+                params_flat, stacked_inputs, stacked_state
+            )
             outs = {
                 cl.client_id: [np.asarray(o[b]) for o in wire_outs]
                 for b, (cl, _) in enumerate(members)
@@ -669,6 +672,7 @@ class RRTOEdgeServer:
         fault: Optional["FaultInjector"] = None,
         admission: Optional[AdmissionController] = None,
         verify: bool = False,
+        jax_device: Optional[Any] = None,
     ):
         self.clock = clock or SimClock()
         self.name = name
@@ -684,7 +688,7 @@ class RRTOEdgeServer:
         )
         self.server = OffloadServer(
             server_device, execute=execute, replay_cache=self.cache,
-            name=name, tracer=tracer, verify=verify,
+            name=name, tracer=tracer, verify=verify, jax_device=jax_device,
         )
         self.ingress = ingress or ServerIngress()
         if tracer is not None:

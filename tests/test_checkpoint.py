@@ -102,11 +102,11 @@ class TestStore:
         here; the 256<->512-chip reshard is exercised by the dry-run meshes)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.distributed.sharding import compat_make_mesh
+        from repro.distributed.sharding import auto_mesh
 
         tree = make_tree()
         store.save(str(tmp_path), 2, tree)
-        mesh = compat_make_mesh((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         shardings = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
         restored = store.restore(str(tmp_path), 2, tree, shardings=shardings)
         np.testing.assert_array_equal(

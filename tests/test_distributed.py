@@ -2,6 +2,7 @@
 int8 gradient compression with error feedback, straggler mitigation."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,8 +14,7 @@ from repro.distributed.compression import (
     quantize_int8,
 )
 from repro.distributed.sharding import (
-    compat_make_mesh,
-    get_shard_map,
+    auto_mesh,
     translate_spec,
     zero1_spec,
 )
@@ -53,10 +53,10 @@ class TestCompression:
         assert float(err) <= float(scale) * 0.5 + 1e-6
 
     def test_compressed_psum_shard_map(self, rng):
-        mesh = compat_make_mesh((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         x = jnp.asarray(rng.normal(0, 1, (64,)).astype(np.float32))
 
-        shard_map = get_shard_map()
+        shard_map = jax.shard_map
 
         f = shard_map(
             lambda v: compressed_psum(v, "data")[0],
@@ -77,8 +77,8 @@ class TestCompression:
         x = jnp.asarray(rng.normal(0, 1, (256,)).astype(np.float32))
         err = jnp.zeros_like(x)
         applied = jnp.zeros_like(x)
-        mesh = compat_make_mesh((1,), ("data",))
-        shard_map = get_shard_map()
+        mesh = auto_mesh((1,), ("data",))
+        shard_map = jax.shard_map
 
         step = shard_map(
             lambda v, e: compressed_psum(v, "data", e),

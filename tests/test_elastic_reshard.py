@@ -21,8 +21,8 @@ _SAVE = textwrap.dedent(
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import store
 
-    from repro.distributed.sharding import compat_make_mesh
-    mesh = compat_make_mesh((2, 2), ("data", "model"))
+    from repro.distributed.sharding import auto_mesh
+    mesh = auto_mesh((2, 2), ("data", "model"))
     w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
     sharded = jax.device_put(w, NamedSharding(mesh, P("data", "model")))
     tree = {"params": {"w": sharded}, "step": jnp.int32(9)}
@@ -41,8 +41,8 @@ _RESTORE = textwrap.dedent(
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.checkpoint import store
 
-    from repro.distributed.sharding import compat_make_mesh
-    mesh = compat_make_mesh((2, 1), ("data", "model"))
+    from repro.distributed.sharding import auto_mesh
+    mesh = auto_mesh((2, 1), ("data", "model"))
     target = {
         "params": {"w": jax.ShapeDtypeStruct((64, 32), jnp.float32)},
         "step": jax.ShapeDtypeStruct((), jnp.int32),

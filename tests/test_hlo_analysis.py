@@ -48,10 +48,10 @@ class TestWeightedAnalysis:
     def test_collectives_detected(self):
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed.sharding import compat_make_mesh, get_shard_map
+        from repro.distributed.sharding import auto_mesh
 
-        mesh = compat_make_mesh((1,), ("d",))
-        shard_map = get_shard_map()
+        mesh = auto_mesh((1,), ("d",))
+        shard_map = jax.shard_map
 
         f = shard_map(
             lambda v: jax.lax.psum(v, "d"), mesh=mesh,

@@ -187,3 +187,74 @@ class TestSSMScan:
         y, h_f = ssm_scan(x, dt, A, Bm, Cm, D, chunk=8)
         np.testing.assert_allclose(np.asarray(y), y_naive, rtol=3e-4, atol=3e-4)
         np.testing.assert_allclose(np.asarray(h_f), h_naive, rtol=3e-4, atol=3e-4)
+
+
+class TestKernelPaths:
+    """Which implementation each dispatch takes on a TPU backend, and why —
+    the choice is named, never silent."""
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        import repro.kernels.dispatch as dispatch
+
+        monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+
+    @pytest.mark.parametrize(
+        "q_shape,k_shape,impl",
+        [
+            ((1, 16, 128), (1, 512, 8, 128), "pallas"),     # qwen3 served bucket
+            ((1, 16, 128), (1, 144, 8, 128), "pallas"),     # one whole tile
+            ((1, 32, 64), (1, 512, 32, 64), "reference"),   # 64-wide heads
+            ((1, 16, 128), (1, 1000, 8, 128), "reference"),  # no whole tiles
+        ],
+    )
+    def test_decode_attention_path(self, on_tpu, q_shape, k_shape, impl):
+        from repro.kernels.decode_attention import decode_attention_path
+
+        path = decode_attention_path(q_shape, k_shape)
+        assert path.impl == impl and path.reason
+
+    def test_reference_on_tpu_is_logged(self, on_tpu, caplog):
+        from repro.kernels.decode_attention import decode_attention_path
+
+        with caplog.at_level("WARNING", logger="repro.kernels"):
+            decode_attention_path((1, 32, 64), (1, 512, 32, 64))
+        assert "head dim 64" in caplog.text
+
+    @pytest.mark.parametrize(
+        "sq,impl", [(512, "pallas"), (300, "reference")]
+    )
+    def test_flash_attention_path(self, on_tpu, sq, impl):
+        from repro.kernels.flash_attention.ops import flash_attention_path
+
+        assert flash_attention_path((1, sq, 16, 128), (1, sq, 8, 128)).impl == impl
+
+    @pytest.mark.parametrize(
+        "chunk,seq,impl",
+        [(128, 512, "pallas"), (64, 64, "pallas"), (16, 48, "reference")],
+    )
+    def test_gated_scan_path(self, on_tpu, chunk, seq, impl):
+        from repro.kernels.ssm_scan.ops import gated_scan_path
+
+        assert gated_scan_path(chunk, seq).impl == impl
+
+    def test_cpu_takes_reference_and_says_so(self):
+        from repro.kernels.rmsnorm.ops import rmsnorm_path
+
+        path = rmsnorm_path()
+        assert path.impl == "reference" and "backend" in path.reason
+        assert rmsnorm_path(interpret=True).impl == "interpret"
+
+    def test_vmapped_decode_kernel_matches_ref(self, rng):
+        import jax
+        import jax.numpy as jnp
+
+        q = rng.normal(0, 1, (3, 1, 8, 128)).astype(np.float32)
+        kc = rng.normal(0, 1, (3, 1, 256, 2, 128)).astype(np.float32)
+        vc = rng.normal(0, 1, (3, 1, 256, 2, 128)).astype(np.float32)
+        kv_len = jnp.asarray([[17], [200], [256]], jnp.int32)
+        out = jax.vmap(
+            lambda *a: decode_attention(*a, interpret=True)
+        )(q, kc, vc, kv_len)
+        ref = jax.vmap(decode_attention_ref)(q, kc, vc, kv_len)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)

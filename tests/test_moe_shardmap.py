@@ -31,15 +31,15 @@ _SCRIPT = textwrap.dedent(
     )
     cfg_sm = dataclasses.replace(cfg, moe_groups=2)
 
-    from repro.distributed.sharding import compat_make_mesh, use_mesh
-    mesh = compat_make_mesh((2, 2), ("data", "model"))
+    from repro.distributed.sharding import auto_mesh
+    mesh = auto_mesh((2, 2), ("data", "model"))
     rng = np.random.default_rng(0)
     p = moe_mod.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jnp.asarray(rng.normal(0, 1, (4, 8, 32)).astype(np.float32))
 
     y_base = moe_mod.moe_apply(p, x, cfg)   # global dispatch, no mesh needed
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         f = jax.jit(lambda p_, x_: moe_mod.moe_apply(p_, x_, cfg_sm),
                     in_shardings=(None, NamedSharding(mesh, P(("data",), None, None))),
                     out_shardings=NamedSharding(mesh, P(("data",), None, None)))

@@ -18,11 +18,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ArchConfig
-from repro.core.engine import (
-    BoundSegmentedReplay,
-    SegmentedReplayProgram,
-    _quiet_donation,
-)
+from repro.core.engine import BoundSegmentedReplay, SegmentedReplayProgram
 from repro.core.offload import OffloadableModel, OffloadSession
 from repro.models.cnn_zoo import make_recurrent_sensor_decoder
 from repro.partition import (
@@ -143,10 +139,9 @@ class TestStatefulSplitEquivalence:
             ref_state = [jnp.asarray(s) for s in state0]
             split_env = dict(env)
             for step in range(steps):
-                with _quiet_donation():
-                    ref_outs, ref_state = program.step_fn(
-                        params_flat, [np.asarray(w) for w in wire], ref_state
-                    )
+                ref_outs, ref_state = program.step_fn(
+                    params_flat, [np.asarray(w) for w in wire], ref_state
+                )
                 ref_state = list(ref_state)
                 outs = bound.execute(wire, split_env)
                 assert len(outs) == len(ref_outs)
@@ -205,11 +200,10 @@ class TestStatefulSplitEquivalence:
         bound.carried_state = [jnp.asarray(s) for s in state0_b]
         wire = sess_b.replay_wire_inputs(model.example_inputs)
         params_flat = [env_b[a] for a in ref_bound.param_addrs]
-        with _quiet_donation():
-            ref_outs, _ = ref_bound.program.step_fn(
-                params_flat, [np.asarray(w) for w in wire],
-                [jnp.asarray(s) for s in state0_b],
-            )
+        ref_outs, _ = ref_bound.program.step_fn(
+            params_flat, [np.asarray(w) for w in wire],
+            [jnp.asarray(s) for s in state0_b],
+        )
         outs = bound.execute(wire, env_b)
         for got, want in zip(outs, ref_outs):
             assert np.array_equal(np.asarray(got), np.asarray(want))
