@@ -56,7 +56,7 @@ from repro.core.records import (
     InferenceSequence,
     OperatorRecord,
 )
-from repro.obs import MetricsRegistry, RegistryBackedStats, Tracer
+from repro.obs import MetricsRegistry, RegistryBackedStats, Tracer, host_span
 from typing import TYPE_CHECKING
 
 if TYPE_CHECKING:  # pragma: no cover — avoids core <-> partition import cycle
@@ -1178,34 +1178,40 @@ class OffloadServer:
             if program.is_stateful:
                 return [np.zeros(*avals[j]) for j in program.wire_out]
             return [np.zeros(s, d) for s, d in avals]
-        params_flat = [ctx.env[a] for a in bound.param_addrs]
-        if program.is_stateful:
-            if bound.carried_state is None:
-                raise RuntimeError(
-                    f"stateful replay for {client_id!r} has no seeded "
-                    "carried state"
-                )
-            if fresh_carried:
-                for idx, v in fresh_carried.items():
-                    bound.carried_state[idx] = self.to_device(v)
-            wire = [np.asarray(x) for x in inputs]
-            wire_outs, new_carried = program.step_fn(
-                params_flat, wire, bound.carried_state
-            )
-            bound.carried_state = list(new_carried)
-            wire_outs = [np.asarray(o) for o in wire_outs]
-            self._refresh_env(ctx, bound, wire, wire_outs)
-            return wire_outs
-        outs = program.fn(params_flat, [np.asarray(x) for x in inputs])
-        outs = [np.asarray(o) for o in outs]
-        # refresh the env (inputs AND outputs) so a post-fallback
-        # recording-phase catch-up replays against this inference's
-        # buffers, not the last recorded one's
-        for addr, val in zip(bound.h2d_addrs, inputs):
-            ctx.env[addr] = np.asarray(val)
-        for addr, val in zip(bound.d2h_addrs, outs):
-            ctx.env[addr] = val
-        return outs
+        with host_span("rrto.replay"):
+            params_flat = [ctx.env[a] for a in bound.param_addrs]
+            if program.is_stateful:
+                if bound.carried_state is None:
+                    raise RuntimeError(
+                        f"stateful replay for {client_id!r} has no seeded "
+                        "carried state"
+                    )
+                if fresh_carried:
+                    with host_span("rrto.fresh_upload"):
+                        for idx, v in fresh_carried.items():
+                            bound.carried_state[idx] = self.to_device(v)
+                wire = [np.asarray(x) for x in inputs]
+                with host_span("rrto.launch"):
+                    wire_outs, new_carried = program.step_fn(
+                        params_flat, wire, bound.carried_state
+                    )
+                bound.carried_state = list(new_carried)
+                with host_span("rrto.fetch"):
+                    wire_outs = [np.asarray(o) for o in wire_outs]
+                self._refresh_env(ctx, bound, wire, wire_outs)
+                return wire_outs
+            with host_span("rrto.launch"):
+                outs = program.fn(params_flat, [np.asarray(x) for x in inputs])
+            with host_span("rrto.fetch"):
+                outs = [np.asarray(o) for o in outs]
+            # refresh the env (inputs AND outputs) so a post-fallback
+            # recording-phase catch-up replays against this inference's
+            # buffers, not the last recorded one's
+            for addr, val in zip(bound.h2d_addrs, inputs):
+                ctx.env[addr] = np.asarray(val)
+            for addr, val in zip(bound.d2h_addrs, outs):
+                ctx.env[addr] = val
+            return outs
 
     @staticmethod
     def _refresh_env(
@@ -1243,15 +1249,16 @@ class OffloadServer:
             return
         ctx = self.context(client_id)
         bound = ctx.replay
-        if bound.program.is_stateful:
-            if new_carried is not None:
-                bound.carried_state = list(new_carried)
-            self._refresh_env(ctx, bound, list(inputs), list(outs))
-            return
-        for addr, val in zip(bound.h2d_addrs, inputs):
-            ctx.env[addr] = np.asarray(val)
-        for addr, val in zip(bound.d2h_addrs, outs):
-            ctx.env[addr] = val
+        with host_span("rrto.adopt", client=client_id):
+            if bound.program.is_stateful:
+                if new_carried is not None:
+                    bound.carried_state = list(new_carried)
+                self._refresh_env(ctx, bound, list(inputs), list(outs))
+                return
+            for addr, val in zip(bound.h2d_addrs, inputs):
+                ctx.env[addr] = np.asarray(val)
+            for addr, val in zip(bound.d2h_addrs, outs):
+                ctx.env[addr] = val
 
     # -- carried-state migration --------------------------------------------
     def export_carried_state(
@@ -1372,6 +1379,7 @@ class InferenceStats(RegistryBackedStats):
         ("wall_seconds", 0.0),
         ("joules", 0.0),
         ("cache_adoptions", 0),
+        ("replayed_records", 0),      # IOS records of replayed inferences
         # fault-tolerance counters (all zero without a FaultInjector)
         ("retries", 0),               # lost-message timeouts paid
         ("dedup_replies", 0),         # retried steps answered from the cache
@@ -2011,6 +2019,7 @@ class RRTOClient:
 
         if self._replay_pos == 0:
             # STARTRRTO: new inference begins (Alg. 3 line 12)
+            self.stats.replayed_records += len(self.ios)
             self._replay_prefix = []
             self._replay_inputs = []
             self._replay_outputs = None

@@ -53,7 +53,7 @@ from repro.core.netsim import (
     RetryPolicy,
     get_network,
 )
-from repro.obs import MetricsRegistry, Tracer
+from repro.obs import MetricsRegistry, Tracer, host_span
 from repro.partition.planner import PartitionConfig
 
 SYSTEMS = ("device_only", "nnto", "cricket", "semi_rrto", "rrto")
@@ -324,12 +324,13 @@ class OffloadSession:
             )
             self._aux_addrs = {i: a for i, a in enumerate(aux_addrs)}
         values, resident = self._steady_invars(inputs)
-        return self.interceptor.run(
-            self._steady_jaxpr,
-            self._param_addrs_for(self._steady_jaxpr),
-            values,
-            resident_inputs=resident,
-        )
+        with host_span("rrto.intercept", client=self.client_id):
+            return self.interceptor.run(
+                self._steady_jaxpr,
+                self._param_addrs_for(self._steady_jaxpr),
+                values,
+                resident_inputs=resident,
+            )
 
     def replay_wire_inputs(self, inputs: Sequence[Any]) -> List[np.ndarray]:
         """The HtoD payloads one replay-phase inference of ``inputs`` ships,

@@ -1,4 +1,4 @@
-"""Sim-clock tracing: nested spans, instants, and counter samples.
+"""Tracing on two clocks: sim-clock spans, and host spans on the real one.
 
 The :class:`Tracer` is a plain in-memory event sink on the *simulated*
 timebase — every timestamp is a ``SimClock``/``EventTimeline`` time in
@@ -22,12 +22,25 @@ is open on the same track records it as its parent.  ``span`` emits a
 complete (begin+end) span in one call and also parents under the current
 open span of its track — the common shape here, because the simulators
 know an interval's begin *and* end at the same program point.
+
+Host spans
+----------
+:func:`host_span` is the other half: a named interval of the program's own
+host work on the *host* clock, written into JAX's profiler trace, which
+aligns it with the device's operations.  It has no switch: it records
+nothing unless a profiler session is running (``jax.profiler.trace``), and
+entering and leaving one costs one to two microseconds of host time either
+way.  Names are fixed ``rrto.*`` strings; identifiers (a client id, a batch
+width) go in as args, never into the name, so one site is one name in the
+trace.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -143,3 +156,9 @@ class Tracer:
         for c in self.counters:
             seen.setdefault(c.track)
         return list(seen)
+
+
+def host_span(name: str, **args: Any) -> TraceAnnotation:
+    """A host-clock span for ``with``: ``name`` is an ``rrto.*`` string,
+    ``args`` are recorded beside it in the profiler trace."""
+    return TraceAnnotation(name, **args)

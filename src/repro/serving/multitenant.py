@@ -65,7 +65,7 @@ from repro.core.engine import (
 )
 from repro.core.netsim import FaultInjector, ServerIngress, get_network
 from repro.core.offload import InferenceResult, OffloadableModel, OffloadSession
-from repro.obs import MetricsRegistry, RegistryBackedStats, Tracer
+from repro.obs import MetricsRegistry, RegistryBackedStats, Tracer, host_span
 from repro.partition.segments import PLACE_SERVER
 from repro.serving.admission import AdmissionController, drr_select
 from repro.serving.replay_cache import ReplayCache
@@ -160,6 +160,7 @@ class BatcherStats(RegistryBackedStats):
         ("batched_replays", 0),      # submissions served from a batch
         ("solo_replays", 0),         # submissions that fell back to solo
         ("vmap_batches", 0),         # groups executed as one true vmap call
+        ("param_compares", 0),       # co-tenant weight leaves compared on device
         ("vmap_compiles", 0),        # batched executables built (not cached)
         ("vmap_compiles_avoided", 0),  # widths served by a padded executable
         ("vmap_padded_lanes", 0),    # masked lanes executed across batches
@@ -497,23 +498,27 @@ class ReplayBatcher:
         first_ctx = self.server.context(members[0][0].client_id)
         first_bound = first_ctx.replay
         params = [first_ctx.env[a] for a in first_bound.param_addrs]
-        for cl, _ in members[1:]:
-            ctx = self.server.context(cl.client_id)
-            bound = ctx.replay
-            if bound is None or bound.program is not first_bound.program:
-                return None
-            theirs = [ctx.env[a] for a in bound.param_addrs]
-            for mine, other in zip(params, theirs):
-                if mine is other:
-                    continue
-                # compared where the buffers live: no host round trip
-                if (
-                    mine.shape != other.shape
-                    or mine.dtype != other.dtype
-                    or not bool(jnp.array_equal(mine, other))
-                ):
+        compares = 0
+        try:
+            for cl, _ in members[1:]:
+                ctx = self.server.context(cl.client_id)
+                bound = ctx.replay
+                if bound is None or bound.program is not first_bound.program:
                     return None
-        return params
+                theirs = [ctx.env[a] for a in bound.param_addrs]
+                for mine, other in zip(params, theirs):
+                    if mine is other:
+                        continue
+                    if mine.shape != other.shape or mine.dtype != other.dtype:
+                        return None
+                    # compared where the buffers live: no host round trip
+                    compares += 1
+                    if not bool(jnp.array_equal(mine, other)):
+                        return None
+            return params
+        finally:
+            # one bump per call, not one per leaf
+            self.param_compares += compares
 
     def _run_vmap_batch(
         self,
@@ -571,71 +576,78 @@ class ReplayBatcher:
         served.add(width)
         self.vmap_padded_lanes += padded - width
         pad = padded - width
-        stacked_inputs = [
-            np.stack(
-                [np.asarray(m[1][k]) for m in members]
-                + [np.asarray(members[0][1][k])] * pad
-            )
-            for k in range(len(members[0][1]))
-        ]
-        if program.is_stateful:
+        with host_span("rrto.batch_stack"):
+            stacked_inputs = [
+                np.stack(
+                    [np.asarray(m[1][k]) for m in members]
+                    + [np.asarray(members[0][1][k])] * pad
+                )
+                for k in range(len(members[0][1]))
+            ]
             stacked_state = [
                 jnp.stack([st[k] for st in states] + [states[0][k]] * pad)
                 for k in range(len(states[0]))
-            ]
-            wire_outs, new_carried = batched.fn(
-                params_flat, stacked_inputs, stacked_state
-            )
-            outs = {
-                cl.client_id: [np.asarray(o[b]) for o in wire_outs]
-                for b, (cl, _) in enumerate(members)
-            }
-            carried = {
-                cl.client_id: [c[b] for c in new_carried]
-                for b, (cl, _) in enumerate(members)
-            }
+            ] if program.is_stateful else None
+        if program.is_stateful:
+            with host_span("rrto.launch"):
+                wire_outs, new_carried = batched.fn(
+                    params_flat, stacked_inputs, stacked_state
+                )
+            with host_span("rrto.batch_unstack"):
+                outs = {
+                    cl.client_id: [np.asarray(o[b]) for o in wire_outs]
+                    for b, (cl, _) in enumerate(members)
+                }
+                carried = {
+                    cl.client_id: [c[b] for c in new_carried]
+                    for b, (cl, _) in enumerate(members)
+                }
             return _BatchGroup(0.0, {}, outs=outs, carried=carried)
-        raw = batched.fn(params_flat, stacked_inputs)
-        outs = {
-            cl.client_id: [np.asarray(o[b]) for o in raw]
-            for b, (cl, _) in enumerate(members)
-        }
+        with host_span("rrto.launch"):
+            raw = batched.fn(params_flat, stacked_inputs)
+        with host_span("rrto.batch_unstack"):
+            outs = {
+                cl.client_id: [np.asarray(o[b]) for o in raw]
+                for b, (cl, _) in enumerate(members)
+            }
         return _BatchGroup(0.0, {}, outs=outs)
 
     def _execute_group(self, fp: Optional[str], t: float) -> Optional[_BatchGroup]:
         members = self._pending.pop(fp, None) if fp is not None else None
         if not members:
             return None
-        first = members[0][0]
-        program = self.server.context(first.client_id).replay.program
-        # the batch slot count is the admitted membership; a member that ends
-        # up falling back mid-walk still occupied its scheduled slot
-        batch = len(members)
-        group: Optional[_BatchGroup] = None
-        if batch > 1 and self.server.execute and self.enable_vmap:
-            params_flat = self._shared_params(members)
-            if params_flat is not None:
-                group = self._run_vmap_batch(fp, members, params_flat)
-                if group is not None:
-                    self.vmap_batches += 1
-        if group is None:
-            group = _BatchGroup(done_at=0.0, pending={})
-        compute = program.batched_compute_seconds(self.server.device, batch)
-        # a lone submitter flushes immediately; a real group waits out the
-        # batching window for its co-tenants before the one-shot execution
-        start = t + (self.window_s if batch > 1 else 0.0)
-        group.done_at = self.server.occupy(compute, start)
-        group.pending = {cl.client_id: wire for cl, wire in members}
-        group.digest = self._wire_digest(first.client_id)
-        self._groups[fp] = group
-        self.batches_executed += 1
-        self.batch_sizes.append(batch)
-        if self.tracer is not None:
-            self.tracer.span(
-                f"{self.track}/batcher", "batch_round", start, group.done_at,
-                fp=fp, width=batch, vmap=group.outs is not None,
-            )
-        return group
+        with host_span("rrto.batch", width=len(members)):
+            first = members[0][0]
+            program = self.server.context(first.client_id).replay.program
+            # the batch slot count is the admitted membership; a member that ends
+            # up falling back mid-walk still occupied its scheduled slot
+            batch = len(members)
+            group: Optional[_BatchGroup] = None
+            if batch > 1 and self.server.execute and self.enable_vmap:
+                with host_span("rrto.batch_params_check"):
+                    params_flat = self._shared_params(members)
+                if params_flat is not None:
+                    group = self._run_vmap_batch(fp, members, params_flat)
+                    if group is not None:
+                        self.vmap_batches += 1
+            if group is None:
+                group = _BatchGroup(done_at=0.0, pending={})
+            compute = program.batched_compute_seconds(self.server.device, batch)
+            # a lone submitter flushes immediately; a real group waits out the
+            # batching window for its co-tenants before the one-shot execution
+            start = t + (self.window_s if batch > 1 else 0.0)
+            group.done_at = self.server.occupy(compute, start)
+            group.pending = {cl.client_id: wire for cl, wire in members}
+            group.digest = self._wire_digest(first.client_id)
+            self._groups[fp] = group
+            self.batches_executed += 1
+            self.batch_sizes.append(batch)
+            if self.tracer is not None:
+                self.tracer.span(
+                    f"{self.track}/batcher", "batch_round", start, group.done_at,
+                    fp=fp, width=batch, vmap=group.outs is not None,
+                )
+            return group
 
 
 def _delegate_stat(name: str) -> property:
@@ -785,29 +797,30 @@ class RRTOEdgeServer:
                 self.sessions[cid].client.deadline_t = (
                     self.admission.deadline_for(cid, self.clock.t)
                 )
-        entries: Dict[str, List[Tuple[RRTOClient, List[np.ndarray]]]] = {}
-        seg_entries: Dict[Tuple[str, int, int], List[str]] = {}
-        for cid, inputs in inputs_by_client.items():
-            sess = self.sessions[cid]
-            cl = sess.client
-            # full-server replays batch as whole programs (key = the full
-            # replay identity); split-plan clients run their own segmented
-            # schedule, but their *server-resident* segments still batch —
-            # keyed by (fingerprint, segment bounds), so co-tenants on
-            # different device-side cuts of one shared IOS share the GPU slot
-            if cl.mode != MODE_REPLAYING or cl.replay_key is None:
-                continue
-            if cl.split_plan is None:
-                entries.setdefault(cl.replay_key, []).append(
-                    (cl, sess.replay_wire_inputs(inputs))
-                )
-            else:
-                for seg in cl.split_plan.segments:
-                    if seg.placement == PLACE_SERVER:
-                        seg_entries.setdefault(
-                            (cl.ios_fp, seg.start, seg.end), []
-                        ).append(cid)
-        self.batcher.begin_round(entries, seg_entries)
+        with host_span("rrto.round_prepare"):
+            entries: Dict[str, List[Tuple[RRTOClient, List[np.ndarray]]]] = {}
+            seg_entries: Dict[Tuple[str, int, int], List[str]] = {}
+            for cid, inputs in inputs_by_client.items():
+                sess = self.sessions[cid]
+                cl = sess.client
+                # full-server replays batch as whole programs (key = the full
+                # replay identity); split-plan clients run their own segmented
+                # schedule, but their *server-resident* segments still batch —
+                # keyed by (fingerprint, segment bounds), so co-tenants on
+                # different device-side cuts of one shared IOS share the GPU slot
+                if cl.mode != MODE_REPLAYING or cl.replay_key is None:
+                    continue
+                if cl.split_plan is None:
+                    entries.setdefault(cl.replay_key, []).append(
+                        (cl, sess.replay_wire_inputs(inputs))
+                    )
+                else:
+                    for seg in cl.split_plan.segments:
+                        if seg.placement == PLACE_SERVER:
+                            seg_entries.setdefault(
+                                (cl.ios_fp, seg.start, seg.end), []
+                            ).append(cid)
+            self.batcher.begin_round(entries, seg_entries)
         self.batcher.sample_depth(self.clock.t)
         if self.admission is not None:
             # refresh the ingress queue-depth gauge on the sim clock

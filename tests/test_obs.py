@@ -17,6 +17,7 @@ Load-bearing properties, in order:
 import json
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -26,6 +27,7 @@ from repro.obs import (
     MetricsRegistry,
     RegistryBackedStats,
     Tracer,
+    host_span,
     percentile,
     to_chrome_trace,
     write_chrome_trace,
@@ -33,6 +35,7 @@ from repro.obs import (
 from repro.partition.planner import plan_cost, plan_partition
 from repro.partition.segments import SegmentGraph
 from repro.serving import EdgeFleet
+from repro.serving.multitenant import RRTOEdgeServer
 
 MBPS = 1e6 / 8.0
 
@@ -295,6 +298,86 @@ class TestDisabledTracer:
         assert base_sum["fleet"] == t_sum["fleet"]
         assert base_sum["router"] == t_sum["router"]
         assert base_sum["backhaul_bytes"] == t_sum["backhaul_bytes"]
+
+
+# ---------------------------------------------------------------------------
+# host spans on the profiler clock, and the counters at their boundaries
+# ---------------------------------------------------------------------------
+def _edge_rounds(n_clients=3, rounds=5):
+    """Co-tenants of one model through vmap-batched rounds: each round's
+    outputs, and the batcher's counters after every round."""
+    model, x = make_mlp(3)
+    edge = RRTOEdgeServer(execute=True)
+    for _ in range(n_clients):
+        edge.connect(model)
+    ids = list(edge.sessions)
+    outs, stats = [], []
+    for _ in range(rounds):
+        res = edge.run_round({c: (x,) for c in ids})
+        outs.append([np.asarray(res[c].outputs[0]) for c in ids])
+        stats.append(edge.batcher.stats.as_dict())
+    return outs, stats, edge
+
+
+class TestHostSpans:
+    def test_host_span_is_a_profiler_annotation(self):
+        span = host_span("rrto.replay", client="u0")
+        assert isinstance(span, jax.profiler.TraceAnnotation)
+        with span:
+            pass
+
+    def test_profiler_on_is_bitwise_identical(self, tmp_path):
+        base_outs, base_stats, base_sum = TestDisabledTracer._run(None)
+        base_edge, base_batcher, _ = _edge_rounds()
+        with jax.profiler.trace(str(tmp_path)):
+            p_outs, p_stats, p_sum = TestDisabledTracer._run(None)
+            p_edge, p_batcher, _ = _edge_rounds()
+        for a, b in zip(base_outs, p_outs):
+            assert np.array_equal(a, b)
+        assert base_stats == p_stats
+        assert base_sum["fleet"] == p_sum["fleet"]
+        for round_a, round_b in zip(base_edge, p_edge):
+            for a, b in zip(round_a, round_b):
+                assert np.array_equal(a, b)
+        assert base_batcher == p_batcher
+        assert p_batcher[-1]["vmap_batches"] >= 1
+
+    def test_replayed_records_grow_by_the_ios_per_replayed_call(self):
+        model, x = make_mlp(4)
+        sess = OffloadSession(model, "rrto", min_repeats=2)
+        sess.load()
+        while sess.client.mode != "replaying":
+            sess.infer(x)
+        stats = sess.client.stats
+        n = len(sess.client.ios)
+        assert n > 0
+        for _ in range(3):
+            before = stats.replayed_records
+            sess.infer(x)
+            assert stats.replayed_records - before == n
+
+    def test_param_compares_per_vmap_round(self):
+        _, stats, edge = _edge_rounds(n_clients=3, rounds=6)
+        cid = next(iter(edge.sessions))
+        leaves = len(edge.server.context(cid).replay.param_addrs)
+        batched = [(b["vmap_batches"] - a["vmap_batches"],
+                    b["param_compares"] - a["param_compares"])
+                   for a, b in zip(stats, stats[1:])]
+        assert (1, (3 - 1) * leaves) in batched
+        for rounds, compares in batched:
+            assert compares == rounds * (3 - 1) * leaves
+        # co-tenants whose weights differ stop at the first unequal leaf
+        m0, x = make_mlp(0)
+        m1, _ = make_mlp(7)
+        edge = RRTOEdgeServer(execute=True)
+        edge.connect(m0)
+        edge.connect(m1)
+        for _ in range(4):
+            edge.run_round({"c0": (x,), "c1": (x,)})
+        assert edge.batcher.vmap_batches == 0
+        groups = edge.batcher.batch_sizes.count(2)
+        assert groups >= 1
+        assert edge.batcher.param_compares == groups
 
 
 # ---------------------------------------------------------------------------
