@@ -28,7 +28,12 @@ shared pieces:
   (replay key, padded batch width) in the shared :class:`ReplayCache` —
   whose outputs are bitwise identical to the per-client execution loop;
   members with distinct parameters fall back to per-client functional
-  execution under the same modeled batch timing.  Batch widths pad to the
+  execution under the same modeled batch timing.  Sharing is proven once,
+  then aliased: each session uploads its own weights, so a co-tenant leaf
+  found bitwise equal to the first member's is re-pointed to that buffer,
+  and later rounds pass by identity with no device work (sound because
+  arrays are immutable and parameters are never donated; see
+  ``ReplayBatcher._shared_params``).  Batch widths pad to the
   next power of two (masked lanes replay lane 0 and are discarded), so a
   fingerprint compiles O(log N) batched executables instead of one per
   width.  Split-mode co-tenants batch too, at *segment* granularity: their
@@ -161,6 +166,7 @@ class BatcherStats(RegistryBackedStats):
         ("solo_replays", 0),         # submissions that fell back to solo
         ("vmap_batches", 0),         # groups executed as one true vmap call
         ("param_compares", 0),       # co-tenant weight leaves compared on device
+        ("param_aliases", 0),        # co-tenant leaves re-pointed after a proof
         ("vmap_compiles", 0),        # batched executables built (not cached)
         ("vmap_compiles_avoided", 0),  # widths served by a padded executable
         ("vmap_padded_lanes", 0),    # masked lanes executed across batches
@@ -493,20 +499,31 @@ class ReplayBatcher:
     ) -> Optional[List[Any]]:
         """The members' shared parameter buffers, or None when any differ.
 
-        Identity comparison first (co-tenants running the one app binary
-        literally share the leaves), bitwise equality as the slow path."""
+        Proof, then alias, then identity.  A co-tenant leaf that is the
+        first member's buffer passes at once.  Any other leaf of the same
+        shape and dtype is compared bitwise where the buffers live; when
+        equal, the co-tenant's env entry is re-pointed to the first
+        member's buffer, so from the next round on identity decides with no
+        device work and no sync, and the duplicate copy is freed once
+        nothing else holds it.  A proof holds while both objects stay in
+        the env: a ``jax.Array`` is immutable, the replay executables
+        donate only the carried state (argument 2, never the parameters),
+        and a client that writes new weights replaces the entry with a new
+        object, which fails identity and is compared afresh.  Leaves proven
+        equal before a later leaf differs stay aliased, since they are
+        equal."""
         first_ctx = self.server.context(members[0][0].client_id)
         first_bound = first_ctx.replay
         params = [first_ctx.env[a] for a in first_bound.param_addrs]
-        compares = 0
+        compares = aliases = 0
         try:
             for cl, _ in members[1:]:
                 ctx = self.server.context(cl.client_id)
                 bound = ctx.replay
                 if bound is None or bound.program is not first_bound.program:
                     return None
-                theirs = [ctx.env[a] for a in bound.param_addrs]
-                for mine, other in zip(params, theirs):
+                for addr, mine in zip(bound.param_addrs, params):
+                    other = ctx.env[addr]
                     if mine is other:
                         continue
                     if mine.shape != other.shape or mine.dtype != other.dtype:
@@ -515,10 +532,13 @@ class ReplayBatcher:
                     compares += 1
                     if not bool(jnp.array_equal(mine, other)):
                         return None
+                    ctx.env[addr] = mine
+                    aliases += 1
             return params
         finally:
             # one bump per call, not one per leaf
             self.param_compares += compares
+            self.param_aliases += aliases
 
     def _run_vmap_batch(
         self,

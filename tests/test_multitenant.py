@@ -47,6 +47,20 @@ def make_deep_mlp(seed=0, d=16):
     return OffloadableModel(f"deep{seed}", apply, params, (x,)), x
 
 
+def make_rnn():
+    """A stateful co-tenant app: the state output feeds the next call."""
+    rng = np.random.default_rng(0)
+    params = {"w": rng.normal(0, 0.1, (8, 8)).astype(np.float32)}
+
+    def apply(p, x, state):
+        new_state = jnp.tanh(state @ p["w"] + x)
+        return [new_state.sum(axis=1), new_state]
+
+    x = rng.normal(0, 1, (2, 8)).astype(np.float32)
+    state0 = np.zeros((2, 8), np.float32)
+    return OffloadableModel("rnn", apply, params, (x, state0)), x, state0
+
+
 class TestFingerprint:
     def test_stable_across_clients(self):
         """Two independent sessions (own interceptor, own allocator) running
@@ -203,6 +217,140 @@ class TestCacheAdoption:
             )
 
 
+class TestParamAliasing:
+    """Co-tenants that each uploaded the same weights: the first vmap round
+    proves every leaf equal on the device and re-points the co-tenants' env
+    entries to the first member's buffer, so later rounds pass by identity
+    with no compare."""
+
+    @staticmethod
+    def _edge(n):
+        model, x = make_mlp()
+        edge = RRTOEdgeServer(execute=True)
+        for _ in range(n):
+            edge.connect(model)
+        return edge, list(edge.sessions), model, x
+
+    @staticmethod
+    def _leaves(edge, cid):
+        ctx = edge.server.context(cid)
+        return [ctx.env[a] for a in ctx.replay.param_addrs]
+
+    def test_first_vmap_round_aliases_every_leaf(self):
+        edge, ids, _, x = self._edge(4)
+        for _ in range(8):
+            edge.run_round({c: (x,) for c in ids})
+            if edge.batcher.vmap_batches:
+                break
+        assert edge.batcher.vmap_batches == 1
+        first = self._leaves(edge, ids[0])
+        for c in ids[1:]:
+            assert all(
+                a is b for a, b in zip(self._leaves(edge, c), first)
+            )
+
+    def test_compares_stop_after_the_proof(self):
+        edge, ids, _, x = self._edge(3)
+        per_round = []
+        for _ in range(7):
+            before = (edge.batcher.vmap_batches, edge.batcher.param_compares)
+            edge.run_round({c: (x,) for c in ids})
+            per_round.append((edge.batcher.vmap_batches - before[0],
+                              edge.batcher.param_compares - before[1]))
+        leaves = len(edge.server.context(ids[0]).replay.param_addrs)
+        vmap_compares = [n for batches, n in per_round if batches]
+        assert len(vmap_compares) >= 3
+        assert vmap_compares[0] == (3 - 1) * leaves
+        assert not any(vmap_compares[1:])
+        assert edge.batcher.param_compares == (3 - 1) * leaves
+        assert edge.batcher.param_aliases == (3 - 1) * leaves
+
+    @pytest.mark.parametrize("app", ["mlp", "rnn"])
+    def test_aliased_rounds_bitwise_equal_loop(self, app):
+        model, x0, state0 = (make_rnn() if app == "rnn"
+                             else (*make_mlp(), None))
+        rng = np.random.default_rng(11)
+        ids = [f"c{i}" for i in range(3)]
+        feeds = [{c: rng.normal(0, 1, x0.shape).astype(np.float32)
+                  for c in ids} for _ in range(7)]
+
+        def run(enable_vmap):
+            edge = RRTOEdgeServer(execute=True)
+            edge.batcher.enable_vmap = enable_vmap
+            for _ in ids:
+                edge.connect(model)
+            states = {c: state0 for c in ids}
+            rounds = []
+            for feed in feeds:
+                res = edge.run_round({
+                    c: (feed[c],) if state0 is None else (feed[c], states[c])
+                    for c in ids
+                })
+                if state0 is not None:
+                    states = {c: res[c].outputs[1] for c in ids}
+                rounds.append({c: [np.asarray(o) for o in res[c].outputs]
+                               for c in ids})
+            return rounds, edge
+
+        vmapped, edge_v = run(True)
+        looped, edge_l = run(False)
+        assert edge_v.batcher.vmap_batches >= 3
+        assert edge_v.batcher.param_aliases > 0
+        assert edge_l.batcher.vmap_batches == 0
+        for rv, rl in zip(vmapped, looped):
+            for c in ids:
+                for a, b in zip(rv[c], rl[c]):
+                    np.testing.assert_array_equal(a, b)
+
+    def test_rewritten_weight_is_compared_and_falls_back(self):
+        edge, ids, model, x = self._edge(3)
+        for _ in range(6):
+            before = edge.run_round({c: (x,) for c in ids})
+        assert edge.batcher.vmap_batches >= 2
+        shared = self._leaves(edge, ids[0])
+        ctx = edge.server.context(ids[1])
+        w1_addr = next(a for a in ctx.replay.param_addrs
+                       if ctx.env[a].shape == model.params["w1"].shape)
+        w1_new = np.random.default_rng(3).normal(
+            0, 0.1, model.params["w1"].shape).astype(np.float32)
+        # a client that writes new weights replaces its env entry
+        rewritten = ctx.env[w1_addr] = edge.server.to_device(w1_new)
+        batches = edge.batcher.vmap_batches
+        compares = edge.batcher.param_compares
+        after = edge.run_round({c: (x,) for c in ids})
+        assert edge.batcher.vmap_batches == batches
+        assert edge.batcher.param_compares == compares + 1
+        ref = np.asarray(jax.jit(model.apply)(
+            dict(model.params, w1=w1_new), x)[0])
+        np.testing.assert_allclose(
+            np.asarray(after[ids[1]].outputs[0]), ref, rtol=1e-5, atol=1e-5
+        )
+        assert not np.allclose(np.asarray(before[ids[1]].outputs[0]), ref)
+        for c in (ids[0], ids[2]):
+            np.testing.assert_array_equal(
+                np.asarray(after[c].outputs[0]),
+                np.asarray(before[c].outputs[0]),
+            )
+            assert all(a is b for a, b in zip(self._leaves(edge, c), shared))
+        # the differing leaf is never aliased over; the proven one stays
+        for a, mine in zip(ctx.replay.param_addrs, shared):
+            assert ctx.env[a] is (rewritten if a == w1_addr else mine)
+
+    def test_new_first_member_passes_by_identity(self):
+        edge, ids, _, x = self._edge(4)
+        for _ in range(6):
+            edge.run_round({c: (x,) for c in ids})
+        assert edge.batcher.param_aliases == 3 * len(
+            edge.server.context(ids[0]).replay.param_addrs)
+        # the first member replays alone; the next group leads with another
+        edge.run_round({ids[0]: (x,)})
+        batches = edge.batcher.vmap_batches
+        compares = edge.batcher.param_compares
+        edge.run_round({c: (x,) for c in ids[1:]})
+        assert edge.batcher.vmap_batches == batches + 1
+        assert edge.batcher.param_compares == compares
+
+
 class TestPaddedVmapWidths:
     def test_padded_widths_reuse_executables(self):
         """Batch widths pad to the next power of two: a width-3 round reuses
@@ -287,18 +435,6 @@ class TestPaddedVmapWidths:
         padded lanes or avoided compiles may be recorded for the aborted
         batch — they would inflate the padding accounting for lanes that
         never executed."""
-
-        def make_rnn():
-            rng = np.random.default_rng(0)
-            params = {"w": rng.normal(0, 0.1, (8, 8)).astype(np.float32)}
-
-            def apply(p, x, state):
-                new_state = jnp.tanh(state @ p["w"] + x)
-                return [new_state.sum(axis=1), new_state]
-
-            x = rng.normal(0, 1, (2, 8)).astype(np.float32)
-            state0 = np.zeros((2, 8), np.float32)
-            return OffloadableModel("rnn", apply, params, (x, state0)), x, state0
 
         model, x, state0 = make_rnn()
         edge = RRTOEdgeServer(execute=True)

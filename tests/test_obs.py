@@ -363,9 +363,13 @@ class TestHostSpans:
         batched = [(b["vmap_batches"] - a["vmap_batches"],
                     b["param_compares"] - a["param_compares"])
                    for a, b in zip(stats, stats[1:])]
-        assert (1, (3 - 1) * leaves) in batched
-        for rounds, compares in batched:
-            assert compares == rounds * (3 - 1) * leaves
+        # the first vmap round proves each co-tenant leaf once and aliases
+        # it; every later round passes by buffer identity, with no compare
+        vmap_rounds = [compares for rounds, compares in batched if rounds]
+        assert vmap_rounds[0] == (3 - 1) * leaves
+        assert len(vmap_rounds) >= 2 and not any(vmap_rounds[1:])
+        assert sum(compares for _, compares in batched) == (3 - 1) * leaves
+        assert stats[-1]["param_aliases"] == (3 - 1) * leaves
         # co-tenants whose weights differ stop at the first unequal leaf
         m0, x = make_mlp(0)
         m1, _ = make_mlp(7)
