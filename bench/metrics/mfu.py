@@ -1,7 +1,7 @@
 """Model FLOP/s utilization of the traced window: the operations one decode
-call needs (bench/flops.py, each client's call counted once, wasted and
-padded lanes not at all), over the traced window's length and the chip's
-peak bf16 rate."""
+call needs (the family's ``decode_step``, each client's call counted once,
+wasted and padded lanes not at all), over the traced window's length and
+the chip's peak bf16 rate."""
 from bench import flops
 
 
@@ -11,6 +11,6 @@ def read(run):
     traced = [c for c in run.calls if c.traced]
     if not traced:
         return None
-    hf = run.spec["config"]
-    ops = sum(flops.decode_step(hf, c.kv_len)[0] for c in traced)
+    step, hf = flops.family(run.spec).decode_step, run.spec["config"]
+    ops = sum(step(hf, c.kv_len)[0] for c in traced)
     return 100.0 * ops / (run.trace["window_s"] * run.peak["bf16_flops_per_s"])

@@ -1,6 +1,7 @@
 """Qwen3 dense decoder: the benchmark's side of one model family.
 
-Three things live here, and nothing of them comes from the program:
+Five things live here (the family contract of ``bench/models/__init__.py``),
+and nothing of them comes from the program:
 
 * ``program_config``: the program's ``ArchConfig`` for the published sizes in
   a configuration file (the registry entry named there, with every size
@@ -12,13 +13,17 @@ Three things live here, and nothing of them comes from the program:
   before a rotate-half RoPE, SwiGLU MLP, tied embeddings), computed layer by
   layer at ``highest`` matmul precision.  With ``fp8=True`` every weight
   matrix is rounded to float8 e4m3 (per output channel scaled) first: the
-  control, one precision step below the configuration's bfloat16.
+  control, one precision step below the configuration's bfloat16;
+* ``decode_step`` and ``kernel_calls``: the operations and bytes of one
+  token, for the whole step and for each call of the ``decode_attention``
+  and ``rmsnorm`` kernels.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import json
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -128,6 +133,81 @@ def make_params(spec: dict, seed: int):
     hf = spec["config"]
     fn = jax.jit(lambda k: _nest(_leaves(hf, k)))
     return jax.block_until_ready(fn(weight_key(seed)))
+
+
+# ---------------------------------------------------------------------------
+# operation and byte counts (the definitions of bench/models/__init__.py)
+# ---------------------------------------------------------------------------
+
+def _dtype_bytes(hf: dict) -> int:
+    return {"bfloat16": 2, "float16": 2, "float32": 4}[hf["torch_dtype"]]
+
+
+def parameter_count(hf: dict) -> int:
+    """Parameters of a tied-embedding dense decoder (embedding counted once)."""
+    d, ff, L = hf["hidden_size"], hf["intermediate_size"], hf["num_hidden_layers"]
+    hq, hkv, dh = (hf["num_attention_heads"], hf["num_key_value_heads"],
+                   hf["head_dim"])
+    per_layer = d * (hq + 2 * hkv) * dh + hq * dh * d + 3 * d * ff
+    per_layer += 2 * d + 2 * dh
+    return L * per_layer + hf["vocab_size"] * d + d
+
+
+def decode_step(hf: dict, kv_len: int) -> Tuple[float, float]:
+    """(operations, bytes) of one token through the whole model: every
+    weight read once, the cache read up to ``kv_len`` and one position of it
+    written, the logits over the vocabulary."""
+    d, ff, L = hf["hidden_size"], hf["intermediate_size"], hf["num_hidden_layers"]
+    hq, hkv, dh = (hf["num_attention_heads"], hf["num_key_value_heads"],
+                   hf["head_dim"])
+    v = hf["vocab_size"]
+    per_layer = 2 * d * (hq + 2 * hkv) * dh + 2 * hq * dh * d + 6 * d * ff
+    per_layer += 4 * hq * dh * kv_len
+    flops = L * per_layer + 2 * d * v
+    b = _dtype_bytes(hf)
+    kv = 2 * hkv * dh * b
+    nbytes = parameter_count(hf) * b + L * kv * (kv_len + 1)
+    return float(flops), float(nbytes)
+
+
+def decode_attention(hf: dict, kv_len: int) -> Tuple[float, float]:
+    """(operations, bytes) of one decode-attention kernel call (one layer,
+    one client): q against keys and values of ``kv_len`` positions."""
+    hq, hkv, dh = (hf["num_attention_heads"], hf["num_key_value_heads"],
+                   hf["head_dim"])
+    b = _dtype_bytes(hf)
+    flops = 4 * hq * dh * kv_len
+    nbytes = 2 * hq * dh * b + 2 * kv_len * hkv * dh * b
+    return float(flops), float(nbytes)
+
+
+def rmsnorm_calls(hf: dict) -> List[Tuple[int, int]]:
+    """(rows, width) of each RMSNorm kernel call of one token: per layer the
+    attention input, the per-head q and k norms and the MLP input, then the
+    final norm."""
+    d, L = hf["hidden_size"], hf["num_hidden_layers"]
+    hq, hkv, dh = (hf["num_attention_heads"], hf["num_key_value_heads"],
+                   hf["head_dim"])
+    return [(1, d), (hq, dh), (hkv, dh), (1, d)] * L + [(1, d)]
+
+
+def rmsnorm(hf: dict, rows: int, width: int) -> Tuple[float, float]:
+    """(operations, bytes) of one RMSNorm call: square, mean, rsqrt scale
+    and the gain, about 4 operations an element; x read, y written, the gain
+    read."""
+    b = _dtype_bytes(hf)
+    return float(4 * rows * width), float((2 * rows * width + width) * b)
+
+
+def kernel_calls(hf: dict, kv_len: int) -> Dict[str, List[Tuple[int, float, float]]]:
+    """Every kernel call of one token: decode attention once a layer, and
+    each RMSNorm of ``rmsnorm_calls``."""
+    return {
+        "decode_attention": [(hf["num_hidden_layers"],
+                              *decode_attention(hf, kv_len))],
+        "rmsnorm": [(1, *rmsnorm(hf, rows, width))
+                    for rows, width in rmsnorm_calls(hf)],
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,12 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is looked up in ``BENCHMARK.json``; its configuration is
-``bench/configs/<config>.json``, its traffic mix ``bench/traffic/<traffic>.json``,
-whose ``driver`` names ``bench/drivers/<driver>.py``, and every metric is read
-by ``bench/metrics/<metric>.py``.  A run makes its weights and requests from
+``bench/configs/<config>.json``, whose ``model`` names its family module
+``bench/models/<model>.py`` (program config, weights, reference, and the
+operation and byte counts the per-layer metrics read), its traffic mix
+``bench/traffic/<traffic>.json``, whose ``driver`` names
+``bench/drivers/<driver>.py``, and every metric is read by
+``bench/metrics/<metric>.py``.  A run makes its weights and requests from
 ``--seed``, warms up every shape the window uses (set-up), serves the mix for
 ``--seconds`` (the window), then checks the served tokens against the plain
 reference (``correct``).  With ``--trace 1`` the first seconds of the window
@@ -176,7 +179,7 @@ def run_cell(cell: dict, spec: dict, mix: dict, check: dict, bench: dict,
     from bench.serving import CompileCounter, Profiler, RunData
     from bench.traffic import Traffic
 
-    model = importlib.import_module(f"bench.models.{spec['model']}")
+    model = flops.family(spec)
     driver_mod = importlib.import_module(f"bench.drivers.{mix['driver']}")
     compiles = CompileCounter()
     kind = jax.devices()[0].device_kind

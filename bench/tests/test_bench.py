@@ -1,9 +1,12 @@
 """CPU checks of the benchmark harness: the contract of ``BENCHMARK.json``,
 that every name it holds finds its file, the refusal without a chip, both
-drivers end to end at a tiny size, the trace reduction and the operation
-counts, and that a cell is added with new files alone."""
+drivers end to end at a tiny size, the trace reduction, the operation
+counts and the family contract, and that a cell, or a model family, is
+added with new files alone."""
 from __future__ import annotations
 
+import dataclasses
+import importlib
 import importlib.util
 import json
 import os
@@ -297,23 +300,24 @@ def test_trace_reduce_reads_a_recorded_trace(tmp_path):
 
 def test_flops_by_hand():
     from bench import flops
+    from bench.models import qwen3
 
     hf = {"hidden_size": 4, "intermediate_size": 6, "num_hidden_layers": 2,
           "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 2,
           "vocab_size": 10, "torch_dtype": "bfloat16"}
     # per layer: q 4x4, k 4x2, v 4x2, o 4x4, gate/up 4x6, down 6x4, norms
     # 4 + 4 + 2 + 2 -> 16 + 8 + 8 + 16 + 72 + 12 = 132; embedding 40 + 4
-    assert flops.parameter_count(hf) == 2 * 132 + 40 + 4
-    ops, nbytes = flops.decode_step(hf, kv_len=3)
+    assert qwen3.parameter_count(hf) == 2 * 132 + 40 + 4
+    ops, nbytes = qwen3.decode_step(hf, kv_len=3)
     # per layer 2*(32 + 16 + 72) = 240 matmul ops + 4*2*2*3 = 48 attention
     assert ops == 2 * (240 + 48) + 2 * 4 * 10
     # weights once + per layer K and V (1 head x 2 x 2 bytes) x (3 read + 1)
     assert nbytes == (2 * 132 + 44) * 2 + 2 * (2 * 1 * 2 * 2) * 4
-    assert flops.decode_attention(hf, 3) == (4 * 2 * 2 * 3,
+    assert qwen3.decode_attention(hf, 3) == (4 * 2 * 2 * 3,
                                              2 * 2 * 2 * 2 + 2 * 3 * 1 * 2 * 2)
-    assert flops.rmsnorm_calls(hf) == [(1, 4), (2, 2), (1, 2), (1, 4)] * 2 \
+    assert qwen3.rmsnorm_calls(hf) == [(1, 4), (2, 2), (1, 2), (1, 4)] * 2 \
         + [(1, 4)]
-    assert flops.rmsnorm(hf, 2, 2) == (16.0, (2 * 2 * 2 + 2) * 2.0)
+    assert qwen3.rmsnorm(hf, 2, 2) == (16.0, (2 * 2 * 2 + 2) * 2.0)
     peak = flops.peaks("TPU v5 lite")
     assert peak["bf16_flops_per_s"] == 197e12
     assert flops.bound_seconds(197e12, 1.0, peak) == pytest.approx(1.0)
@@ -322,32 +326,166 @@ def test_flops_by_hand():
 
 
 def test_qwen3_1_7b_parameter_count():
-    from bench import flops
+    from bench.models import qwen3
 
     spec = json.loads((BENCH / "configs" / "qwen3-1.7b.json").read_text())
-    assert flops.parameter_count(spec["config"]) == pytest.approx(1.72e9,
+    assert qwen3.parameter_count(spec["config"]) == pytest.approx(1.72e9,
                                                                   rel=0.01)
+
+
+# Qwen3's counts as bench/flops.py computed them before they moved into the
+# family module: (configuration, kv_len, decode_step's operations and bytes)
+PARENT_STEPS = [
+    ("qwen3-1.7b", 1, 3_441_131_520, 3_441_379_328),
+    ("qwen3-1.7b", 77, 3_458_564_096, 3_450_095_616),
+    ("qwen3-1.7b", 511, 3_558_113_280, 3_499_870_208),
+    ("qwen3-0.6b", 77, 1_209_630_720, 1_201_045_504),
+    ("qwen3-0.6b", 511, 1_309_179_904, 1_250_820_096),
+]
+V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def _config(name: str) -> dict:
+    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+@pytest.mark.parametrize("config,kv_len,ops,nbytes", PARENT_STEPS)
+def test_family_decode_step_keeps_the_parent_counts(config, kv_len, ops,
+                                                    nbytes):
+    from bench import flops
+
+    spec = _config(config)
+    assert flops.family(spec).decode_step(spec["config"], kv_len) == \
+        (ops, nbytes)
+
+
+@pytest.mark.parametrize("config,params", [("qwen3-1.7b", 1_720_574_976),
+                                           ("qwen3-0.6b", 596_049_920)])
+def test_family_kernel_calls_keep_the_parent_counts(config, params):
+    from bench import flops
+
+    spec = _config(config)
+    fam, hf = flops.family(spec), spec["config"]
+    assert fam.parameter_count(hf) == params
+    calls = fam.kernel_calls(hf, 77)
+    assert set(calls) == {"decode_attention", "rmsnorm"}
+    assert calls["decode_attention"] == [(28, 630_784.0, 323_584.0)]
+    assert sum(n for n, _, _ in calls["rmsnorm"]) == 113
+
+
+def _run_data(spec: dict, kv_lens, trace=None, peak=V5E):
+    """A run whose traced calls are at ``kv_lens``, with one untraced call
+    that no per-layer count may read."""
+    from bench.serving import CallRecord, RunData
+
+    calls = [CallRecord(0, 0.01, kv, True, 3, "replaying", True)
+             for kv in kv_lens]
+    calls.append(CallRecord(1, 0.01, 200, True, 3, "replaying", False))
+    if trace is None:
+        trace = {"window_s": 4.0, "busy_s": 1.0, "idle_share": 0.75,
+                 "devices": 1, "idle_by_host": {},
+                 "op_seconds": {"decode_attention.6": 2e-3,
+                                "decode_attention.7": 1e-3,
+                                "rmsnorm.3": 4e-4, "fusion.1": 1.0}}
+    return RunData(spec=spec, calls=calls, requests=[], window_s=51.0,
+                   setup={}, counters={}, trace=trace, peak=peak)
+
+
+@pytest.mark.parametrize("config,kv_lens,step_ops,rmsnorm_bytes", [
+    # chat1: one client, calls at growing lengths
+    ("qwen3-1.7b", (1, 77, 511), (3_441_131_520, 3_458_564_096,
+                                  3_558_113_280), 1_058_816),
+    # edge8: a batched round's members, two at one length
+    ("qwen3-0.6b", (77, 511, 511), (1_209_630_720, 1_309_179_904,
+                                    1_309_179_904), 708_608),
+])
+def test_per_layer_readers_keep_the_parent_readings(config, kv_lens, step_ops,
+                                                    rmsnorm_bytes):
+    """The readers as the parent wrote them, by hand: both configurations
+    have 28 layers of 16 query and 8 KV heads of 128, so a decode-attention
+    call reads 8192 + 4096·kv_len bytes, and every one of its calls and of a
+    token's 113 RMSNorm calls (``rmsnorm_bytes`` in all) is bound by its
+    bytes on a v5e."""
+    from bench import run
+
+    data = _run_data(_config(config), kv_lens)
+    want = {
+        "mfu": 100 * sum(step_ops) / (4.0 * 197e12),
+        "decode_attention_roofline":
+            100 * 28 * sum(8192 + 4096 * kv for kv in kv_lens) / 819e9 / 3e-3,
+        "rmsnorm_roofline": 100 * len(kv_lens) * rmsnorm_bytes / 819e9 / 4e-4,
+    }
+    for name, value in want.items():
+        assert run.read_metric(name, data) == pytest.approx(value, rel=1e-9)
+
+
+def test_kernel_roofline_reads_nothing_where_there_is_nothing():
+    from bench import flops
+
+    spec = _config("qwen3-0.6b")
+    assert flops.kernel_roofline(_run_data(spec, (77,)), "rmsnorm") > 0
+    no_kernel = _run_data(spec, (77,))
+    no_kernel.trace["op_seconds"]["matmul_kernel.2"] = 1e-3
+    for data, kernel in [
+        (_run_data(spec, (77,), peak=None), "rmsnorm"),
+        (dataclasses.replace(_run_data(spec, (77,)), trace=None), "rmsnorm"),
+        (_run_data(spec, (77,)), "matmul_kernel"),   # no time in the trace
+        (no_kernel, "matmul_kernel"),                # not the family's
+        (_run_data(spec, ()), "rmsnorm"),            # no traced call
+    ]:
+        assert flops.kernel_roofline(data, kernel) is None
+
+
+def test_every_family_keeps_the_contract(bench):
+    """Every module in bench/models/ has the five functions, and each
+    configuration's family gives finite positive counts at both ends of
+    its cache bucket."""
+    import math
+
+    from bench import flops
+
+    names = ("program_config", "make_params", "logit_stats", "decode_step",
+             "kernel_calls")
+    for path in (BENCH / "models").glob("*.py"):
+        if path.name == "__init__.py":
+            continue
+        mod = importlib.import_module(f"bench.models.{path.stem}")
+        for name in names:
+            assert callable(getattr(mod, name, None)), (path.name, name)
+    for c in bench["configs"]:
+        spec = json.loads((ROOT / c["file"]).read_text())
+        fam, hf = flops.family(spec), spec["config"]
+        for kv_len in (1, spec["bucket_len"]):
+            counts = list(fam.decode_step(hf, kv_len))
+            calls = fam.kernel_calls(hf, kv_len)
+            assert calls, c["name"]
+            for entries in calls.values():
+                assert entries
+                for n, ops, nbytes in entries:
+                    assert isinstance(n, int) and n > 0
+                    counts += [ops, nbytes]
+            assert all(math.isfinite(x) and x > 0 for x in counts), c["name"]
 
 
 # ---------------------------------------------------------------------------
 # a cell added with new files only
 # ---------------------------------------------------------------------------
 
-def test_a_cell_is_added_with_new_files_only(tmp_path, bench):
-    """Copy the benchmark, add a throwaway configuration, traffic mix,
-    check and per-layer metric as new files plus new entries in
-    BENCHMARK.json, and run the new cell with no existing file edited."""
+def _copy_with_cell(tmp_path, bench, model: str) -> dict:
+    """Copy the benchmark under ``tmp_path`` and add the cell
+    ``tiny.throwaway``: a tiny configuration of the family ``model``, a
+    traffic mix and a check as new files, and new entries in (the returned
+    copy of) BENCHMARK.json."""
     shutil.copytree(BENCH, tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     spec = tiny_spec()
     spec["name"] = "tiny"
+    spec["model"] = model
     (tmp_path / "bench/configs/tiny.json").write_text(json.dumps(spec))
     (tmp_path / "bench/traffic/throwaway.json").write_text(
         json.dumps(tiny_mix("chat1", 1)))
     (tmp_path / "bench/checks/tiny.throwaway.json").write_text(
         json.dumps({"max_logit_gap": 0.25}))
-    (tmp_path / "bench/metrics/calls_in_window.py").write_text(
-        "def read(run):\n    return len(run.calls)\n")
     new = json.loads(json.dumps(bench))
     new["configs"].append({"name": "tiny", "source": spec["source"],
                            "file": "bench/configs/tiny.json", "reduced": [],
@@ -355,34 +493,110 @@ def test_a_cell_is_added_with_new_files_only(tmp_path, bench):
     new["workloads"].append({"name": "tiny.throwaway", "config": "tiny",
                              "traffic": "throwaway", "chips": 1,
                              "why": "throwaway"})
-    new["per_layer"].append({"name": "calls_in_window", "unit": "calls",
-                             "better": "higher", "source": "host_clock",
-                             "layer": "client protocol",
-                             "moves": "call_p50_ms",
-                             "workloads": ["tiny.throwaway"]})
+    return new
+
+
+def _run_copy(tmp_path, new: dict, script: str) -> dict:
+    """Write the copy's BENCHMARK.json, run ``script`` (which prints one
+    JSON line) against the copy on the CPU, and check that no file the
+    benchmark already had was edited."""
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(new))
+    head = ("import json, sys\n"
+            f"sys.path[:0] = [{str(tmp_path)!r}, {str(ROOT / 'src')!r}]\n"
+            "from bench import run\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", head + script],
+                          cwd=tmp_path, env=env, capture_output=True,
+                          text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
     before = {p.relative_to(BENCH): p.read_bytes()
               for p in BENCH.rglob("*") if p.is_file()
               and "__pycache__" not in p.parts}
     for rel, data in before.items():
         assert (tmp_path / "bench" / rel).read_bytes() == data
-    script = (
-        "import json, sys\n"
-        f"sys.path[:0] = [{str(tmp_path)!r}, {str(ROOT / 'src')!r}]\n"
-        "from bench import run\n"
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_a_cell_is_added_with_new_files_only(tmp_path, bench):
+    """Copy the benchmark, add a throwaway configuration, traffic mix,
+    check and per-layer metric as new files plus new entries in
+    BENCHMARK.json, and run the new cell with no existing file edited."""
+    new = _copy_with_cell(tmp_path, bench, "qwen3")
+    (tmp_path / "bench/metrics/calls_in_window.py").write_text(
+        "def read(run):\n    return len(run.calls)\n")
+    new["per_layer"].append({"name": "calls_in_window", "unit": "calls",
+                             "better": "higher", "source": "host_clock",
+                             "layer": "client protocol",
+                             "moves": "call_p50_ms",
+                             "workloads": ["tiny.throwaway"]})
+    res = _run_copy(tmp_path, new, (
         "bench, cell, spec, mix, check = run.cell_files('tiny.throwaway')\n"
         "res = run.run_cell(cell, spec, mix, check, bench, 5, 1.0, True,\n"
         "                   require_tpu=False)\n"
-        "print(json.dumps(res))\n"
-    )
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "-c", script], cwd=tmp_path,
-                          env=env, capture_output=True, text=True,
-                          timeout=600)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    res = json.loads(proc.stdout.strip().splitlines()[-1])
+        "print(json.dumps(res))\n"))
     assert res["correct"] is True
     assert res["metrics"]["calls_in_window"]["value"] > 0
+
+
+THROWAWAY_FAMILY = '''"""Qwen3's program, weights and reference, with every
+operation and byte count doubled."""
+from bench.models import qwen3
+from bench.models.qwen3 import logit_stats, make_params, program_config
+
+
+def decode_step(hf, kv_len):
+    ops, nbytes = qwen3.decode_step(hf, kv_len)
+    return 2 * ops, 2 * nbytes
+
+
+def kernel_calls(hf, kv_len):
+    return {k: [(2 * n, ops, nbytes) for n, ops, nbytes in calls]
+            for k, calls in qwen3.kernel_calls(hf, kv_len).items()}
+'''
+
+
+def test_a_family_is_added_with_new_files_only(tmp_path, bench):
+    """A second family joins as a new module with counts of its own, its
+    cell named in ``mfu``'s workloads: the cell runs, and ``mfu`` and the
+    kernel rooflines read that family's counts (here twice Qwen3's) on the
+    run's own traced calls."""
+    new = _copy_with_cell(tmp_path, bench, "throwaway")
+    (tmp_path / "bench/models/throwaway.py").write_text(THROWAWAY_FAMILY)
+    mfu = next(m for m in new["per_layer"] if m["name"] == "mfu")
+    mfu["workloads"].append("tiny.throwaway")
+    out = _run_copy(tmp_path, new, (
+        "import dataclasses\n"
+        "from bench import flops\n"
+        "seen = []\n"
+        "read = run.read_metric\n"
+        "run.read_metric = lambda name, data: seen.append(data) or \\\n"
+        "    read(name, data)\n"
+        "bench, cell, spec, mix, check = run.cell_files('tiny.throwaway')\n"
+        "res = run.run_cell(cell, spec, mix, check, bench, 5, 1.0, True,\n"
+        "                   require_tpu=False)\n"
+        "trace = {'window_s': 1.0, 'op_seconds': {'decode_attention.1': 1e-3,\n"
+        "                                         'rmsnorm.2': 1e-3}}\n"
+        "data = dataclasses.replace(seen[-1], trace=trace,\n"
+        "                           peak=flops.peaks('TPU v5 lite'))\n"
+        "as_qwen3 = dataclasses.replace(data, spec={**data.spec,\n"
+        "                                           'model': 'qwen3'})\n"
+        "readings = {label: [read('mfu', d),\n"
+        "                    flops.kernel_roofline(d, 'decode_attention'),\n"
+        "                    flops.kernel_roofline(d, 'rmsnorm')]\n"
+        "            for label, d in (('family', data), ('qwen3', as_qwen3))}\n"
+        "print(json.dumps({\n"
+        "    'correct': res['correct'], 'readings': readings,\n"
+        "    'traced': sum(c.traced for c in data.calls),\n"
+        "    'module': flops.family(spec).__file__,\n"
+        "    'per_layer': [m['name'] for m in\n"
+        "                  run.metric_names(bench, 'tiny.throwaway', True)]}))\n"))
+    assert out["correct"] is True
+    assert out["traced"] > 0
+    assert out["module"] == str(tmp_path / "bench/models/throwaway.py")
+    assert "mfu" in out["per_layer"]
+    family, qwen3 = out["readings"]["family"], out["readings"]["qwen3"]
+    assert all(q > 0 for q in qwen3)
+    assert family == pytest.approx([2 * q for q in qwen3], rel=1e-12)
 
 
 def test_metric_readers_import_nothing_of_the_program():
@@ -391,3 +605,16 @@ def test_metric_readers_import_nothing_of_the_program():
         assert "repro" not in text, path.name
         spec = importlib.util.spec_from_file_location("m", path)
         assert spec is not None
+
+
+def test_no_reader_names_a_model_key(bench):
+    """A model's shapes are read in its family module alone: no metric
+    reader, and not bench/flops.py, names a key of a configuration's
+    published ``config``."""
+    keys = set()
+    for c in bench["configs"]:
+        keys |= set(json.loads((ROOT / c["file"]).read_text())["config"])
+    for path in [*(BENCH / "metrics").glob("*.py"), BENCH / "flops.py"]:
+        text = path.read_text()
+        named = {k for k in keys if f'"{k}"' in text or f"'{k}'" in text}
+        assert not named, (path.name, sorted(named))
